@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.common import SELECT_PRECISION
 from repro.kernels.stages import Stage
 from repro.optim import adamw, constant_schedule
 
@@ -79,7 +80,7 @@ def project(params: dict, e: jax.Array, dropout_rng=None, dropout: float = 0.1) 
     x = e
     n = len(params["layers"])
     for i, layer in enumerate(params["layers"]):
-        x = x @ layer["w"] + layer["b"]
+        x = jnp.matmul(x, layer["w"], precision=SELECT_PRECISION) + layer["b"]
         if dropout_rng is not None:
             keep = jax.random.bernoulli(jax.random.fold_in(dropout_rng, i), 1 - dropout, x.shape)
             x = jnp.where(keep, x / (1 - dropout), 0.0)

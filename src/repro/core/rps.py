@@ -24,7 +24,9 @@ round-trip between each — the fused-vs-staged A/B baseline in
 construction (same stage applies, same floats).  Numpy remains the reference
 implementation (``use_kernel=False``, and always for single-query
 ``select``).  The two engines make identical decisions modulo exact float
-ties: the fused pass scores in float32 (numpy accumulates in float64), so
+ties: the fused pass scores in float32 (numpy accumulates in float64; every
+device selection dot asks for full float32, ``kernels.common.SELECT_PRECISION``,
+since a TPU's default float32 dot is one bfloat16 pass), so
 candidates within ~1 ulp of each other can in principle resolve
 differently, and an EXACT similarity tie at the kNN boundary resolves to
 the lowest index in the fused pass but to an unspecified tied member in

@@ -33,6 +33,14 @@ import jax.numpy as jnp
 # inf - inf; anything below NEG_INF / 2 is "masked", anything above is real.
 NEG_INF = -1e30
 
+# Selection is a float32 contract (``core/rps.py``).  On a TPU a float32 dot
+# at DEFAULT precision is one bfloat16 pass, enough to move kNN membership
+# and argmax decisions, so every selection dot asks for full float32.  On a
+# TPU v5e, 128 queries x 100,352 rows x 256-d, top-16: DEFAULT put 155 of
+# the 2,048 ids wrong beyond float64 near-ties (score error up to 6.2e-4),
+# HIGHEST none (error 6.5e-8).
+SELECT_PRECISION = jax.lax.Precision.HIGHEST
+
 
 def is_tpu() -> bool:
     """True when the default JAX backend is a TPU."""

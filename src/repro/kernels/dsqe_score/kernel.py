@@ -165,7 +165,7 @@ def dsqe_score_kernel(
             pltpu.VMEM((block_q, knn), jnp.float32),  # running kNN vals
             pltpu.VMEM((block_q, knn), jnp.int32),  # running kNN train ids
         ],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(q, protos, train, path_weights, contains, lat, cost, prior, valid, slo)

@@ -26,10 +26,12 @@ mode alongside the float32-vs-float64 score ulp caveat.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.common import NEG_INF
+from repro.kernels.common import NEG_INF, SELECT_PRECISION
 
 __all__ = ["NEG_INF", "dsqe_score_from_topk", "dsqe_score_ref"]
 
@@ -58,7 +60,8 @@ def dsqe_score_from_topk(z, topk_vals, topk_ids, protos, path_weights,
     prior = prior.reshape(1, -1)
     valid = valid.reshape(1, -1)
 
-    psims = z @ protos.T  # (Bq, K)
+    dot = functools.partial(jnp.matmul, precision=SELECT_PRECISION)
+    psims = dot(z, protos.T)  # (Bq, K)
     if proto_valid is not None:
         psims = jnp.where(proto_valid.reshape(1, -1) > 0.5, psims, NEG_INF)
     set_id = jnp.argmax(psims, axis=1)  # first max wins on exact ties
@@ -68,10 +71,10 @@ def dsqe_score_from_topk(z, topk_vals, topk_ids, protos, path_weights,
     # scatter the k vote weights back over N via a dense one-hot contraction
     # (XLA CPU lowers this ~30% faster than an .at[].add scatter)
     onehot = jax.nn.one_hot(topk_ids, N, dtype=jnp.float32)  # (Bq,k,N)
-    votes = jnp.einsum("bkn,bk->bn", onehot, w)
-    scores = votes @ path_weights + prior
+    votes = jnp.einsum("bkn,bk->bn", onehot, w, precision=SELECT_PRECISION)
+    scores = dot(votes, path_weights) + prior
 
-    feas_set = set_onehot @ contains
+    feas_set = dot(set_onehot, contains)
     feasible = ((feas_set > 0.5) & (valid > 0.5)
                 & (lat <= slo[:, 0:1]) & (cost <= slo[:, 1:2]))
     return jnp.where(feasible, scores, NEG_INF), set_id.astype(jnp.int32)
@@ -88,7 +91,8 @@ def dsqe_score_ref(q, protos, train, path_weights, contains, lat, cost,
     """
     Bq = q.shape[0]
     slo = jnp.broadcast_to(jnp.asarray(slo, jnp.float32).reshape(-1, 2), (Bq, 2))
-    tsims = q @ train.T  # (Bq, N) — same GEMM as retrieval_topk_ref
+    # (Bq, N): the same GEMM as retrieval_topk_ref
+    tsims = jnp.matmul(q, train.T, precision=SELECT_PRECISION)
     k = min(knn, train.shape[0])
     vals, idx = jax.lax.top_k(tsims, k)  # stable: lowest index first on ties
     return dsqe_score_from_topk(q, vals, idx, protos, path_weights, contains,

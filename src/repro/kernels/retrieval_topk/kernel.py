@@ -32,7 +32,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import NEG_INF
+from repro.kernels.common import NEG_INF, SELECT_PRECISION
 
 
 def topk_merge(run_vals, run_ids, scores, ids, k: int):
@@ -68,7 +68,8 @@ def _topk_kernel(q_ref, corpus_ref, vals_ref, ids_ref, run_v, run_i, *,
 
     q = q_ref[...]  # (block_q, d)
     c = corpus_ref[...]  # (block_n, d) — streamed tile
-    s = jax.lax.dot_general(q, c, (((1,), (1,)), ((), ())))  # (block_q, block_n)
+    s = jax.lax.dot_general(q, c, (((1,), (1,)), ((), ())),
+                            precision=SELECT_PRECISION)  # (block_q, block_n)
     gid = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + j * block_n
     s = jnp.where(gid < n_valid, s, NEG_INF)  # padded corpus rows never win
     v, i = topk_merge(run_v[...], run_i[...], s, gid, k)
@@ -122,7 +123,7 @@ def retrieval_topk_kernel(
             pltpu.VMEM((block_q, k), jnp.float32),  # running champion vals
             pltpu.VMEM((block_q, k), jnp.int32),  # running champion ids
         ],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(q, corpus)

@@ -15,7 +15,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.common import NEG_INF
+from repro.kernels.common import NEG_INF, SELECT_PRECISION
 
 __all__ = ["NEG_INF", "retrieval_topk_ref"]
 
@@ -26,6 +26,6 @@ def retrieval_topk_ref(q, corpus, *, k: int):
     Shapes: q (Bq, d), corpus (n, d).  Returns (scores (Bq, k) float32,
     ids (Bq, k) int32), scores descending, exact ties lowest-id first.
     """
-    scores = q @ corpus.T  # (Bq, n)
+    scores = jnp.matmul(q, corpus.T, precision=SELECT_PRECISION)  # (Bq, n)
     vals, idx = jax.lax.top_k(scores, k)  # stable: lowest index first on ties
     return vals.astype(jnp.float32), idx.astype(jnp.int32)

@@ -58,7 +58,7 @@ from typing import Any, Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.common import NEG_INF
+from repro.kernels.common import NEG_INF, SELECT_PRECISION
 from repro.kernels.dsqe_score.ref import dsqe_score_from_topk
 from repro.kernels.retrieval_topk.ops import retrieval_topk
 
@@ -167,7 +167,7 @@ def shard_projection_stage(layers, *, in_key: str = "emb",
             x = carry[in_key]
             n = len(params_dev)
             for i, (w, b) in enumerate(params_dev):
-                x = x @ w[did] + b[did]
+                x = jnp.matmul(x, w[did], precision=SELECT_PRECISION) + b[did]
                 if i < n - 1:
                     x = jax.nn.relu(x)
             z = x / jnp.maximum(
@@ -205,7 +205,8 @@ def shard_retrieve_stage(corpora, corpus_valid, *, k: int,
         def apply(state_dev, carry: Carry) -> Carry:
             corpus, valid = state_dev
             did = carry[id_key]
-            sims = carry[query_key] @ corpus[did].T  # (B, N_max)
+            sims = jnp.matmul(carry[query_key], corpus[did].T,
+                              precision=SELECT_PRECISION)  # (B, N_max)
             sims = jnp.where(valid[did][None, :] > 0.5, sims, NEG_INF)
             vals, ids = jax.lax.top_k(sims, k)  # stable: lowest index first
             return {**carry, out_vals: vals, out_ids: ids.astype(jnp.int32)}

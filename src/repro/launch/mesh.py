@@ -11,17 +11,23 @@ from __future__ import annotations
 import jax
 
 
+def _auto(n: int) -> tuple:
+    """Auto axis types: the sharding policy places arrays with
+    ``with_sharding_constraint``, which only accepts Auto mesh axes."""
+    return (jax.sharding.AxisType.Auto,) * n
+
+
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, _auto(len(axes)))
 
 
 def make_host_mesh(tp: int = 1) -> jax.sharding.Mesh:
     """Tiny mesh over the actually-available devices (tests / examples)."""
     n = len(jax.devices())
     dp = max(n // tp, 1)
-    return jax.make_mesh((dp, tp), ("data", "model"))
+    return jax.make_mesh((dp, tp), ("data", "model"), _auto(2))
 
 
 def required_devices(multi_pod: bool) -> int:

@@ -41,8 +41,10 @@ from __future__ import annotations
 
 import argparse
 import asyncio
+import os
 import random
 import sys
+from pathlib import Path
 
 import numpy as np
 
@@ -56,6 +58,25 @@ from repro.core.slo import SLO
 from repro.runtime.orchestrator import Overloaded
 from repro.runtime.router import TenantRouter, TenantSpec
 from repro.runtime.server import EcoLLMServer, Request
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for this process; returns
+    its directory.  Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it
+    itself and no other directory is set here; otherwise the cache lives at
+    the fixed ``<checkout>/.jax_cache`` (the path is part of the cache key,
+    so it must not move between runs of one checkout).  Every program is
+    cached, the small per-bucket selection passes included.  Called by
+    entry points only, never on import."""
+    import jax
+
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        # src/repro/launch/serve.py -> the checkout root
+        path = str(Path(__file__).resolve().parents[3] / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    return path
 
 
 def _spec(split: bool, placements: bool) -> dict | None:
@@ -279,6 +300,7 @@ def main() -> None:
         ap.error("--tenants requires --async")
     if args.adapt and not (args.use_async or args.repl):
         ap.error("--adapt requires --async or --repl")
+    enable_compile_cache()
 
     server, test_idx = build_server(args.domain, n_queries=args.queries,
                                     budget=args.budget, lam=int(args.latency_first),
