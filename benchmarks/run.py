@@ -136,19 +136,6 @@ def bench_fleet() -> None:
          f"dup={r.duplicated};counters_exact={r.counters_exact}")
 
 
-def bench_serving() -> None:
-    from benchmarks import async_serving as asv
-
-    t0 = time.time()
-    r = asv.run()
-    print("\n=== Serving: per-query handle vs micro-batched admission ===")
-    print(asv.render(r))
-    _csv("async_serving", (time.time() - t0) * 1e6,
-         f"p50_speedup={r.speedup_p50:.1f}x;p50_orch_ms={r.p50_orch_ms:.1f};"
-         f"p99_orch_ms={r.p99_orch_ms:.1f};shed_rate={r.shed_rate:.3f};"
-         f"mean_bucket={r.mean_bucket:.1f};traces={r.kernel_traces}")
-
-
 def bench_multitenant() -> None:
     from benchmarks import multitenant_serving as mt
 
@@ -245,7 +232,6 @@ BENCHES = {
     "batch": bench_batch,
     "retrieval": bench_retrieval,
     "select": bench_select,
-    "serving": bench_serving,
     "multitenant": bench_multitenant,
     "drift": bench_drift,
     "placement": bench_placement,

@@ -22,12 +22,18 @@ Phases, in order; any failure raises and exits non-zero:
    float64 numpy top-k.  Ids must agree except at near-ties (score gap
    below ``NEAR_TIE``), which are counted.
 
+With ``--trace-dir DIR`` the held-out queries are served once more through
+the ``Orchestrator`` under a profiler session written to ``DIR``: the
+serving path's ``eco.*`` spans on the device operations' clock (the
+recording behind ``tests/data/eco_spans.tpu.xplane.pb``).
+
 The last line of stdout is ``{"ok": true, "device": {...}}``, printed only
 when every phase passed.  Wall times printed are smoke wall times on the
 host clock, compilation included, not device metrics.
 """
 from __future__ import annotations
 
+import argparse
 import asyncio
 import functools
 import json
@@ -62,6 +68,7 @@ SLOS = (SLO(),
 TIE_RTOL = 1e-6  # parity: candidates this close may resolve either way
 NEAR_TIE = 1e-5  # retrieval: rank neighbours this close may swap
 KERNEL_SHAPE = (128, 100_352, 256, 16)  # (Bq, N, d, k)
+TRACE_RATE_QPS = 2000.0  # open-loop rate of the traced serve: many buckets
 
 
 def _rel_close(a: float, b: float, rtol: float = TIE_RTOL) -> bool:
@@ -101,6 +108,19 @@ def serve_phase(n_queries: int = N_QUERIES):
           f"{sorted(buckets)}")
     assert 1 <= rps.kernel_trace_count <= len(buckets)
     return server, reqs
+
+
+def trace_phase(server, reqs, log_dir: str) -> None:
+    """Serve ``reqs`` through the orchestrator at ``TRACE_RATE_QPS`` under a
+    profiler session written to ``log_dir`` (no Python call events)."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with jax.profiler.trace(log_dir, profiler_options=opts):
+        served, shed, stats = asyncio.run(drive_async(
+            server, reqs, max_batch=MAX_BATCH, rate_qps=TRACE_RATE_QPS))
+    _check_served("trace", reqs, served, shed, stats["failed"])
+    print(f"  trace written under {log_dir} (orchestrator totals: "
+          f"{stats['batches']} buckets, {stats['select_passes']} passes)")
 
 
 def fused_kernel_phase(server) -> None:
@@ -200,7 +220,7 @@ def retrieval_phase(shape=KERNEL_SHAPE, seed: int = 0) -> dict:
             "max_score_err": val_err}
 
 
-def main() -> int:
+def main(trace_dir: str | None = None) -> int:
     dev = jax.devices()[0]
     if dev.platform != "tpu":
         print(f"chip_smoke: JAX found no TPU (first device is "
@@ -212,6 +232,8 @@ def main() -> int:
     t0 = time.perf_counter()
     server, reqs = serve_phase()
     try:
+        if trace_dir:
+            trace_phase(server, reqs, trace_dir)
         fused_kernel_phase(server)
         parity_phase(server, reqs)
     finally:
@@ -226,4 +248,6 @@ def main() -> int:
 
 
 if __name__ == "__main__":
-    sys.exit(main())
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--trace-dir", help="record the traced serve here")
+    sys.exit(main(ap.parse_args().trace_dir))

@@ -492,12 +492,18 @@ class RuntimePathSelector:
         and feasibility flags (B,), all as numpy with pad rows sliced off."""
         import jax.numpy as jnp
 
-        embs32, slo32, B = self._pad_bucket(embs, max_lat, max_cost)
-        state, score_pass = self._ensure_kernel(ver)
-        scores, set_ids, best, feas = score_pass(
-            state, jnp.asarray(embs32), jnp.asarray(slo32))
-        return (np.asarray(scores)[:B], np.asarray(set_ids, np.int64)[:B],
-                np.asarray(best, np.int64)[:B], np.asarray(feas)[:B])
+        from repro.runtime import tracing  # runtime imports core: lazily
+
+        with tracing.span("eco.select.pass", rows=embs.shape[0]):
+            embs32, slo32, B = self._pad_bucket(embs, max_lat, max_cost)
+            state, score_pass = self._ensure_kernel(ver)
+            with tracing.span("eco.select.launch"):
+                scores, set_ids, best, feas = score_pass(
+                    state, jnp.asarray(embs32), jnp.asarray(slo32))
+            with tracing.span("eco.select.fetch"):
+                return (np.asarray(scores)[:B],
+                        np.asarray(set_ids, np.int64)[:B],
+                        np.asarray(best, np.int64)[:B], np.asarray(feas)[:B])
 
     # -- Algorithm 3 ----------------------------------------------------------
 
@@ -638,23 +644,28 @@ class RuntimePathSelector:
     def _decisions(self, slo_list, set_ids, best, has_feasible,
                    t0: float, ver: _TableVersion | None = None) -> list[Decision]:
         """Shared epilogue: host-side OOD fallback + Decision construction."""
+        from repro.runtime import tracing  # runtime imports core: lazily
+
         ver = ver if ver is not None else self._ver
         B = len(slo_list)
-        set_l, best_l, feas_l = set_ids.tolist(), best.tolist(), has_feasible.tolist()
-        picks: list[tuple[int, bool]] = []
-        for b in range(B):
-            if feas_l[b]:
-                picks.append((best_l[b], False))
-            else:
-                path = self._fallback(set_l[b], slo_list[b], ver)
-                picks.append((self._path_index[path], True))
-        total_overhead = time.perf_counter() - t0
-        overhead = total_overhead / max(B, 1)  # amortized per-query share
-        return [Decision(ver.table.paths[j], set_l[b], fell_back,
-                         overhead, ver.lat_f[j], ver.cost_f[j],
-                         batch_overhead_s=total_overhead,
-                         table_version=ver.version)
-                for b, (j, fell_back) in enumerate(picks)]
+        with tracing.span("eco.select.decide") as sp:
+            set_l, best_l = set_ids.tolist(), best.tolist()
+            feas_l = has_feasible.tolist()
+            picks: list[tuple[int, bool]] = []
+            for b in range(B):
+                if feas_l[b]:
+                    picks.append((best_l[b], False))
+                else:
+                    path = self._fallback(set_l[b], slo_list[b], ver)
+                    picks.append((self._path_index[path], True))
+            sp.count("fallback", B - sum(feas_l))
+            total_overhead = time.perf_counter() - t0
+            overhead = total_overhead / max(B, 1)  # amortized per-query share
+            return [Decision(ver.table.paths[j], set_l[b], fell_back,
+                             overhead, ver.lat_f[j], ver.cost_f[j],
+                             batch_overhead_s=total_overhead,
+                             table_version=ver.version)
+                    for b, (j, fell_back) in enumerate(picks)]
 
     def _fallback(self, set_id: int, slo: SLO,
                   ver: _TableVersion | None = None) -> Path:
@@ -890,20 +901,26 @@ class DomainShardedSelector:
         single-domain engine; the domain id rides as a traced scalar."""
         import jax.numpy as jnp
 
+        from repro.runtime import tracing  # runtime imports core: lazily
+
         t0 = time.perf_counter()
         sel = self._sel[domain]
         did = self.domain_ids[domain]
         embs, slo_list, max_lat, max_cost = sel._batch_inputs(
             query_embs, slos)
-        embs32, slo32, B = sel._pad_bucket(embs, max_lat, max_cost)
-        state, score_pass, vers = self._ensure_kernel()
-        _, set_ids, best, feas = score_pass(
-            state, jnp.asarray(embs32), jnp.asarray(slo32),
-            jnp.asarray(did, jnp.int32))
-        return sel._decisions(slo_list,
-                              np.asarray(set_ids, np.int64)[:B],
-                              np.asarray(best, np.int64)[:B],
-                              np.asarray(feas)[:B], t0, vers[domain])
+        with tracing.span("eco.select.pass", rows=embs.shape[0], domain=did):
+            embs32, slo32, B = sel._pad_bucket(embs, max_lat, max_cost)
+            state, score_pass, vers = self._ensure_kernel()
+            with tracing.span("eco.select.launch"):
+                _, set_ids, best, feas = score_pass(
+                    state, jnp.asarray(embs32), jnp.asarray(slo32),
+                    jnp.asarray(did, jnp.int32))
+            with tracing.span("eco.select.fetch"):
+                set_ids = np.asarray(set_ids, np.int64)[:B]
+                best = np.asarray(best, np.int64)[:B]
+                feas = np.asarray(feas)[:B]
+        return sel._decisions(slo_list, set_ids, best, feas, t0,
+                              vers[domain])
 
     def select_batch_staged(self, query_embs: np.ndarray, slos,
                             domain: str) -> list[Decision]:

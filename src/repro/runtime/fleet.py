@@ -60,6 +60,8 @@ from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 from typing import Callable, Optional
 
+from repro.runtime import tracing
+
 LAT_WINDOW = 512  # bounded stats window: unbounded lists leaked memory
 
 
@@ -183,11 +185,17 @@ class _Flight:
     __slots__ = ("request", "hedge_allowed", "lock", "done", "result", "meta",
                  "error", "failures", "hedges", "requeues",
                  "tried_failed", "active", "completed", "claims", "callbacks",
-                 "stream", "stream_owner", "chunks", "chunk_cbs", "cancelled")
+                 "stream", "stream_owner", "chunks", "chunk_cbs", "cancelled",
+                 "bucket", "row")
 
-    def __init__(self, request, hedge_allowed: bool, stream: bool = False):
+    def __init__(self, request, hedge_allowed: bool, stream: bool = False,
+                 bucket: Optional[int] = None, row: Optional[int] = None):
         self.request = request
         self.hedge_allowed = hedge_allowed
+        # the admission bucket that fanned it out and its row there: what
+        # its execution spans carry (runtime/tracing.py)
+        self.bucket = bucket
+        self.row = row
         self.lock = threading.Lock()
         self.done = threading.Event()
         # zero-arg completion thunks; None once fired (exactly-once contract)
@@ -464,16 +472,19 @@ class ReplicaFleet:
         return self._run_flights([_Flight(request, hedge)], hedge)[0]
 
     def submit_many(self, requests, hedge: bool = True,
-                    tag: Optional[str] = None):
+                    tag: Optional[str] = None, bucket: Optional[int] = None):
         """Dispatch a batch concurrently across the fleet; results keep the
         input order.  ``max_workers=1`` falls back to the deterministic
         sequential loop.  ``tag`` attributes the dispatch to a caller-chosen
-        bucket (admission shards use ``shard<i>``) in ``snapshot()``."""
+        bucket (admission shards use ``shard<i>``) in ``snapshot()``;
+        ``bucket`` is the admission bucket's id, which the concurrent
+        dispatcher's execution spans carry with each request's row."""
         requests = list(requests)
         self._count_tag(tag, len(requests))
         if self._pool is None:
             return [self._submit_sequential(r, hedge) for r in requests]
-        return self._run_flights([_Flight(r, hedge) for r in requests], hedge)
+        return self._run_flights([_Flight(r, hedge, bucket=bucket, row=i)
+                                  for i, r in enumerate(requests)], hedge)
 
     def _count_tag(self, tag: Optional[str], n: int) -> None:
         if tag is None or n <= 0:
@@ -482,8 +493,8 @@ class ReplicaFleet:
             self.dispatched_by_tag[tag] = self.dispatched_by_tag.get(tag, 0) + n
 
     def submit_many_async(self, requests, hedge: bool = True,
-                          stream: bool = False,
-                          tag: Optional[str] = None) -> list[FleetFuture]:
+                          stream: bool = False, tag: Optional[str] = None,
+                          bucket: Optional[int] = None) -> list[FleetFuture]:
         """Non-blocking fan-out: enqueue the batch and return a
         ``FleetFuture`` per request without waiting for any of them.
 
@@ -498,18 +509,22 @@ class ReplicaFleet:
         With ``max_workers=1`` the deterministic sequential dispatcher runs
         inline and the returned futures are already complete — same RNG
         draw order and accounting as ``submit_many`` (chunks, if streamed,
-        are buffered for replay)."""
+        are buffered for replay).  ``tag`` and ``bucket`` as in
+        ``submit_many``."""
         requests = list(requests)
         self._count_tag(tag, len(requests))
         if self._pool is None:
             if not self.live():  # match the threaded branch: fail at submit
                 raise RuntimeError("no live replicas")
             out = []
-            for r in requests:
-                f = _Flight(r, hedge, stream)
+            for i, r in enumerate(requests):
+                f = _Flight(r, hedge, stream, bucket, i)
                 emit = self._make_emit(f, rid=-1) if stream else None
                 try:
-                    f.result, f.meta = self._submit_sequential(r, hedge, emit)
+                    with tracing.span("eco.fleet.exec", bucket, row=i) as sp:
+                        f.result, f.meta = self._submit_sequential(r, hedge,
+                                                                   emit)
+                        sp.count("won", 1)
                 except Exception as e:  # noqa: BLE001 — surfaced via future
                     # store the ORIGINAL failure (the sequential dispatcher
                     # chains it as __cause__) so FleetFuture.result wraps it
@@ -520,7 +535,8 @@ class ReplicaFleet:
                 self._finish(f)
                 out.append(FleetFuture(f))
             return out
-        flights = [_Flight(r, hedge, stream) for r in requests]
+        flights = [_Flight(r, hedge, stream, bucket, i)
+                   for i, r in enumerate(requests)]
         with self._lock:
             if not self._live:
                 raise RuntimeError("no live replicas")
@@ -784,44 +800,17 @@ class ReplicaFleet:
         if rep is None:
             return
         emit = self._make_emit(f, rid) if f.stream else None
-        try:
-            out, lat = rep.call(f.request, self.rng, emit)
-            err = None
-        except Exception as e:  # noqa: BLE001 — failover path
-            err, out, lat = e, None, 0.0
+        # the span holds the claim too: under load, the fleet lock it takes
+        # is a wait of its own before the response can be built
+        with tracing.span("eco.fleet.exec", f.bucket, row=f.row) as sp:
+            try:
+                out, lat = rep.call(f.request, self.rng, emit)
+                err = None
+            except Exception as e:  # noqa: BLE001 — failover path
+                err, out, lat = e, None, 0.0
+            winner = err is None and self._claim(rid, f, out, lat)
+            sp.count("won", int(winner))
         if err is None:
-            winner = False
-            with self._lock:
-                self._active_by_rid.get(rid, set()).discard(f)
-                with f.lock:
-                    f.active.pop(rid, None)
-                    # a streamed flight is only winnable by its owner: a
-                    # duplicate that ran to completion without ever claiming
-                    # first bytes is a loser even if it lands first
-                    loser = (f.completed or (f.stream_owner is not None
-                                             and f.stream_owner != rid))
-                    if not loser:
-                        winner = True
-                        f.completed = True
-                        # "attempts" = retries + 1, mirroring the sequential
-                        # dispatcher (hedge/requeue duplicates not included
-                        # — those are under their own keys)
-                        f.meta = {"replica": rid, "latency_s": lat,
-                                  "attempts": f.failures + 1,
-                                  "hedges": f.hedges, "requeues": f.requeues,
-                                  "cancelled": f.cancelled,
-                                  "chunks": len(f.chunks)}
-                        f.result = out
-                    else:
-                        # per-flight mirror of cancelled_count; late losers
-                        # update the already-published meta in place (exact
-                        # equality is asserted at quiescence)
-                        f.cancelled += 1
-                        if f.meta is not None:
-                            f.meta["cancelled"] = f.cancelled
-                if not winner:
-                    self.cancelled_count += 1  # loser of a hedge/requeue race
-                self._gc_rid_locked(rid)
             if winner:
                 self._finish(f)
             self._wake.set()
@@ -852,6 +841,43 @@ class ReplicaFleet:
         if give_up:
             self._finish(f)
         self._wake.set()
+
+    def _claim(self, rid: int, f: _Flight, out, lat: float) -> bool:
+        """Record ``rid``'s successful execution of ``f``; True if it won
+        the flight (published its result), False for a losing duplicate."""
+        winner = False
+        with self._lock:
+            self._active_by_rid.get(rid, set()).discard(f)
+            with f.lock:
+                f.active.pop(rid, None)
+                # a streamed flight is only winnable by its owner: a
+                # duplicate that ran to completion without ever claiming
+                # first bytes is a loser even if it lands first
+                loser = (f.completed or (f.stream_owner is not None
+                                         and f.stream_owner != rid))
+                if not loser:
+                    winner = True
+                    f.completed = True
+                    # "attempts" = retries + 1, mirroring the sequential
+                    # dispatcher (hedge/requeue duplicates not included —
+                    # those are under their own keys)
+                    f.meta = {"replica": rid, "latency_s": lat,
+                              "attempts": f.failures + 1,
+                              "hedges": f.hedges, "requeues": f.requeues,
+                              "cancelled": f.cancelled,
+                              "chunks": len(f.chunks)}
+                    f.result = out
+                else:
+                    # per-flight mirror of cancelled_count; late losers
+                    # update the already-published meta in place (exact
+                    # equality is asserted at quiescence)
+                    f.cancelled += 1
+                    if f.meta is not None:
+                        f.meta["cancelled"] = f.cancelled
+            if not winner:
+                self.cancelled_count += 1  # loser of a hedge/requeue race
+            self._gc_rid_locked(rid)
+        return winner
 
     def _hedge_deadline_for(self, exclude) -> Optional[float]:
         with self._lock:

@@ -22,8 +22,11 @@ additionally sheds tickets whose admission deadline lapsed before dispatch.
 Higher ``priority`` tickets are admitted first when a backlog forms.
 
 Every ticket carries a lifecycle timeline (``Ticket.events``):
-``admitted -> selected -> dispatched -> completed`` (or ``... -> shed``),
-stamped with ``time.perf_counter()``.  Selection overheads ride on the
+``admitted -> taken -> selected -> dispatched -> completed`` (or ``... ->
+shed``), stamped with ``time.perf_counter()``; ``taken`` is when the
+admission loop took it off the queue for a bucket.  Each bucket is numbered
+(``Ticket.bucket``), and the spans of its work carry the number
+(``runtime/tracing.py``).  Selection overheads ride on the
 ``Decision`` as before — amortized ``overhead_s`` plus the full
 ``batch_overhead_s`` of the bucket's selection pass.
 
@@ -31,10 +34,9 @@ Streaming contract: a ticket is also an async iterator — ``async for chunk
 in ticket`` yields the response's ``GenChunk``s (split-inference drafts or
 whole-model decode spans) in order, exactly once, as the fleet delivers
 them; ``first_chunk`` lands on the timeline between ``dispatched`` and
-``completed`` and ``Ticket.chunk_times`` records per-chunk arrival stamps.
-The iterator terminates when the ticket settles (completed, shed, or
-failed), so it is safe on non-streaming outcomes too — it just yields
-nothing.  Chunks are a single-consumer side channel; ``await ticket`` is
+``completed``.  The iterator terminates when the ticket settles
+(completed, shed, or failed), so it is safe on non-streaming outcomes too
+— it just yields nothing.  Chunks are a single-consumer side channel; ``await ticket`` is
 unchanged and bit-for-bit identical to the pre-streaming contract (the
 final Response comes from the same non-streamed accounting).
 
@@ -54,6 +56,8 @@ from dataclasses import dataclass
 from typing import TYPE_CHECKING, Optional, Union
 
 import numpy as np
+
+from repro.runtime import tracing
 
 if TYPE_CHECKING:  # circular only for typing: server builds an Orchestrator
     from repro.runtime.server import EcoLLMServer, Request, Response
@@ -82,20 +86,22 @@ class Ticket:
     ``await ticket`` / ``await ticket.wait()`` yields the ``Response`` — or
     an ``Overloaded`` marker if the request was shed.  ``events`` is the
     lifecycle timeline: ``[(name, perf_counter_ts), ...]`` through
-    ``admitted -> selected -> dispatched -> completed`` (``shed`` replaces
-    the tail for rejected tickets; ``failed`` for a bucket whose dispatch
-    raised — awaiting the ticket then re-raises that error).
+    ``admitted -> taken -> selected -> dispatched -> completed`` (``shed``
+    replaces the tail for rejected tickets; ``failed`` for a bucket whose
+    dispatch raised — awaiting the ticket then re-raises that error).
+    ``bucket`` is the id of the admission bucket that took it and ``row``
+    its position among the bucket's dispatched tickets (None until then).
 
     ``async for chunk in ticket`` consumes the streamed partial results
     (module docstring): ordered, exactly-once, terminated when the ticket
     settles.  The first delivered chunk stamps ``first_chunk`` on the
-    timeline; every arrival appends to ``chunk_times``.  Single consumer:
+    timeline.  Single consumer:
     chunks go to whichever iterator reads them first (a second ``async
     for`` after exhaustion terminates immediately).
     """
 
     __slots__ = ("request", "priority", "deadline_s", "deadline_at", "events",
-                 "chunk_times", "_future", "_chunk_q", "_stream_done")
+                 "bucket", "row", "_future", "_chunk_q", "_stream_done")
 
     def __init__(self, request: "Request", priority: int,
                  deadline_s: Optional[float], future: asyncio.Future):
@@ -104,7 +110,8 @@ class Ticket:
         self.deadline_s = deadline_s
         self.deadline_at: Optional[float] = None  # set on admission
         self.events: list[tuple[str, float]] = []
-        self.chunk_times: list[float] = []  # perf_counter per chunk arrival
+        self.bucket: Optional[int] = None
+        self.row: Optional[int] = None
         self._future = future
         self._chunk_q: asyncio.Queue = asyncio.Queue()
         self._stream_done = False
@@ -141,9 +148,8 @@ class Ticket:
         orchestrator's fleet-side chunk forwarder)."""
         if self._stream_done:
             return  # settled already (e.g. raced with an error) — drop
-        if not self.chunk_times:
+        if self.event("first_chunk") is None:
             self.mark("first_chunk")
-        self.chunk_times.append(time.perf_counter())
         self._chunk_q.put_nowait(chunk)
 
     def _end_stream(self) -> None:
@@ -151,6 +157,7 @@ class Ticket:
         if not self._stream_done:
             self._stream_done = True
             self._chunk_q.put_nowait(_STREAM_END)
+            tracing.timeline(self.bucket, self.row, self.events)
 
     async def _iter_chunks(self):
         while True:
@@ -167,6 +174,13 @@ class Ticket:
 
 
 _STOP_PRIO = float("inf")  # sorts after every real ticket in the heap
+
+
+class BucketRows(list):
+    """A bucket's requests, in order, with the bucket's id: what
+    ``Orchestrator._select`` receives."""
+
+    __slots__ = ("bucket",)
 
 
 class Orchestrator:
@@ -224,6 +238,8 @@ class Orchestrator:
         self.dispatched = 0
         self.completed = 0  # executions that produced a Response
         self.failed = 0     # executions whose await re-raises
+        self.select_passes = 0  # selection passes run (one per domain group)
+        self.fallback_rows = 0  # rows decided by the host OOD fallback
         # online adaptation observer (runtime/adaptation.py); None keeps the
         # settle/shed hooks at a single attribute load on the hot path
         self._adaptation = None
@@ -352,6 +368,17 @@ class Orchestrator:
         first); ``deadline_s`` sheds the ticket if it is still waiting for
         dispatch that many seconds after admission.
         """
+        with tracing.span("eco.submit"):
+            ticket = self._admit(request, priority, deadline_s)
+        # yield once per admission: enqueueing itself never suspends, so a
+        # tight submit loop would otherwise starve the admission loop and
+        # spuriously shed a closed workload larger than max_queue
+        await asyncio.sleep(0)
+        return ticket
+
+    def _admit(self, request: "Request", priority: int,
+               deadline_s: Optional[float]) -> Ticket:
+        """The synchronous part of ``submit``: enqueue or shed."""
         loop = asyncio.get_running_loop()
         ticket = Ticket(request, priority, deadline_s, loop.create_future())
         if self._closed:
@@ -377,10 +404,6 @@ class Orchestrator:
             ticket.deadline_at = ticket.events[-1][1] + deadline_s
         with self._stats_lock:
             self.admitted += 1
-        # yield once per admission: enqueueing itself never suspends, so a
-        # tight submit loop would otherwise starve the admission loop and
-        # spuriously shed a closed workload larger than max_queue
-        await asyncio.sleep(0)
         return ticket
 
     def _queue_depth(self) -> int:
@@ -460,6 +483,7 @@ class Orchestrator:
             if entry[2] is None:  # stop sentinel sorts last: queue is drained
                 self._stop_sentinels = max(0, self._stop_sentinels - 1)
                 return
+            entry[2].mark("taken")
             bucket = [entry[2]]
             t0 = time.perf_counter()
             stop = False
@@ -475,17 +499,15 @@ class Orchestrator:
                     self._stop_sentinels = max(0, self._stop_sentinels - 1)
                     stop = True
                     break
+                nxt[2].mark("taken")
                 bucket.append(nxt[2])
-            now = time.perf_counter()
-            live = []
-            for t in bucket:
-                if t.deadline_at is not None and now > t.deadline_at:
-                    self._shed(t, "deadline")
-                else:
-                    live.append(t)
+            bid = tracing.new_bucket()
+            with tracing.span("eco.bucket", bid) as sp:
+                live = self._close_bucket(bucket, bid)
+                sp.count("rows", len(live))
             if live:
                 try:
-                    await self._dispatch(live)
+                    await self._dispatch(live, bid)
                 except Exception as e:  # noqa: BLE001 — fail the bucket,
                     # keep admitting: a dead admission loop would hang every
                     # pending ticket forever
@@ -493,6 +515,24 @@ class Orchestrator:
                         self._fail(t, e)
             if stop:
                 return
+
+    def _close_bucket(self, bucket: list[Ticket], bid: int) -> list[Ticket]:
+        """Shed the bucket's lapsed tickets, number the rest (``bucket``,
+        ``row``) and count them dispatched; returns them."""
+        now = time.perf_counter()
+        live = []
+        for t in bucket:
+            t.bucket = bid
+            if t.deadline_at is not None and now > t.deadline_at:
+                self._shed(t, "deadline")
+            else:
+                t.row = len(live)
+                live.append(t)
+        if live:
+            with self._stats_lock:
+                self.batches += 1
+                self.dispatched += len(live)
+        return live
 
     # -- dispatch ------------------------------------------------------------
 
@@ -505,26 +545,39 @@ class Orchestrator:
         selector, same call); on a multi-domain server the bucket's rows are
         grouped by domain and each group runs through the domain-sharded
         fused program — one traced pass per group with the domain id as a
-        carried scalar, no re-trace per tenant/domain."""
+        carried scalar, no re-trace per tenant/domain.
+
+        ``reqs`` is a ``BucketRows`` where the bucket is numbered; its spans
+        (``eco.select`` and the selector's inside it) carry the number."""
         srv = self.server
-        resolved = [srv._resolve_query(r) for r in reqs]
-        if not srv.is_multi_domain():
-            embs = np.stack([emb for _, emb in resolved])
-            decisions = srv.rps.select_batch(embs, [r.slo for r in reqs])
-        else:
-            sharded = srv.sharded_selector()
-            groups: dict[str, list[int]] = {}
-            for i, r in enumerate(reqs):
-                groups.setdefault(srv.canonical_domain(r.domain), []).append(i)
+        with tracing.span("eco.select", getattr(reqs, "bucket", None),
+                          rows=len(reqs)):
+            with tracing.span("eco.select.resolve"):
+                resolved = [srv._resolve_query(r) for r in reqs]
+                if not srv.is_multi_domain():
+                    groups = {None: list(range(len(reqs)))}
+                else:
+                    groups = {}
+                    for i, r in enumerate(reqs):
+                        groups.setdefault(srv.canonical_domain(r.domain),
+                                          []).append(i)
+                embs = {dom: np.stack([resolved[i][1] for i in idxs])
+                        for dom, idxs in groups.items()}
             decisions = [None] * len(reqs)
             for dom, idxs in groups.items():
-                embs = np.stack([resolved[i][1] for i in idxs])
-                ds = sharded.select_batch(
-                    embs, [reqs[i].slo for i in idxs], dom)
+                slos = [reqs[i].slo for i in idxs]
+                if dom is None:
+                    ds = srv.rps.select_batch(embs[dom], slos)
+                else:
+                    ds = srv.sharded_selector().select_batch(embs[dom], slos,
+                                                             dom)
                 for i, d in zip(idxs, ds):
                     decisions[i] = d
-        jobs = [(query, d.path, r.domain or srv.DEFAULT_DOMAIN)
-                for (query, _), d, r in zip(resolved, decisions, reqs)]
+            with self._stats_lock:
+                self.select_passes += len(groups)
+                self.fallback_rows += sum(d.used_fallback for d in decisions)
+            jobs = [(query, d.path, r.domain or srv.DEFAULT_DOMAIN)
+                    for (query, _), d, r in zip(resolved, decisions, reqs)]
         return resolved, decisions, jobs
 
     def _fleet_tag(self) -> Optional[str]:
@@ -532,21 +585,20 @@ class Orchestrator:
         orchestrator is an admission shard, None (untagged) otherwise."""
         return None if self.shard_id is None else f"shard{self.shard_id}"
 
-    async def _dispatch(self, tickets: list[Ticket]) -> None:
+    async def _dispatch(self, tickets: list[Ticket], bid: int) -> None:
         """Dispatch one bucket without blocking the event loop: selection is
         CPU-bound so it runs on the default executor; the fleet fan-out is
         non-blocking and completes each ticket via callback."""
-        reqs = [t.request for t in tickets]
-        with self._stats_lock:
-            self.batches += 1
-            self.dispatched += len(tickets)
+        reqs = BucketRows(t.request for t in tickets)
+        reqs.bucket = bid
         resolved, decisions, jobs = await self._loop.run_in_executor(
             None, self._select, reqs)
         for t in tickets:
             t.mark("selected")
         futures = self.server.fleet.submit_many_async(jobs, hedge=self.hedge,
                                                       stream=self.stream,
-                                                      tag=self._fleet_tag())
+                                                      tag=self._fleet_tag(),
+                                                      bucket=bid)
         for t in tickets:
             t.mark("dispatched")
         for t, (query, _), dec, fut in zip(tickets, resolved, decisions,
@@ -578,13 +630,15 @@ class Orchestrator:
         srv, loop = self.server, self._loop
 
         def cb(fut):
-            try:
-                result, meta = fut.result(0)
-                resp = srv._respond(ticket.request, query, decision, result,
-                                    meta)
-                err = None
-            except Exception as e:  # noqa: BLE001 — surfaced on the ticket
-                resp, err = None, e
+            with tracing.span("eco.fleet.respond", ticket.bucket,
+                              row=ticket.row):
+                try:
+                    result, meta = fut.result(0)
+                    resp = srv._respond(ticket.request, query, decision,
+                                        result, meta)
+                    err = None
+                except Exception as e:  # noqa: BLE001 — surfaced on the
+                    resp, err = None, e  # ticket
 
             def record():
                 ticket.mark("completed" if err is None else "failed")
@@ -596,13 +650,15 @@ class Orchestrator:
                     self._note_settled(ticket, resp, err)
 
             def settle():
-                record()
-                if not ticket._future.done():
-                    if err is not None:
-                        ticket._future.set_exception(err)
-                    else:
-                        ticket._future.set_result(resp)
-                ticket._end_stream()
+                with tracing.span("eco.settle", ticket.bucket,
+                                  row=ticket.row):
+                    record()
+                    if not ticket._future.done():
+                        if err is not None:
+                            ticket._future.set_exception(err)
+                        else:
+                            ticket._future.set_result(resp)
+                    ticket._end_stream()
 
             try:
                 loop.call_soon_threadsafe(settle)
@@ -623,9 +679,10 @@ class Orchestrator:
         but over the blocking ``submit_many`` so callers get responses
         directly.  ``EcoLLMServer.handle`` / ``handle_batch`` are thin
         wrappers over this — a single request is simply a bucket of one."""
-        reqs = list(reqs)
+        reqs = BucketRows(reqs)
         if not reqs:
             return []
+        reqs.bucket = tracing.new_bucket()
         with self._stats_lock:
             self.admitted += len(reqs)
             self.batches += 1
@@ -633,7 +690,8 @@ class Orchestrator:
         try:
             resolved, decisions, jobs = self._select(reqs)
             outcomes = self.server.fleet.submit_many(jobs, hedge=self.hedge,
-                                                     tag=self._fleet_tag())
+                                                     tag=self._fleet_tag(),
+                                                     bucket=reqs.bucket)
         except Exception:
             with self._stats_lock:  # keep completed + failed == dispatched
                 self.failed += len(reqs)
@@ -663,6 +721,8 @@ class Orchestrator:
                 "dispatched": self.dispatched,
                 "completed": self.completed,
                 "failed": self.failed,
+                "select_passes": self.select_passes,
+                "fallback_rows": self.fallback_rows,
                 "queue_depth": self._queue_depth(),
                 "max_batch": self.max_batch,
                 "max_queue": self.max_queue,

@@ -82,6 +82,7 @@ from dataclasses import dataclass, field
 from typing import TYPE_CHECKING, Iterable, Optional
 
 from repro.core.slo import SLO
+from repro.runtime import tracing
 from repro.runtime.orchestrator import Orchestrator, Ticket
 
 if TYPE_CHECKING:
@@ -243,6 +244,14 @@ class AdmissionShard(Orchestrator):
                      deadline_s: Optional[float] = None) -> Ticket:
         """Per-tenant bounded admission (``Orchestrator.submit`` contract,
         with the queue bound applied to ``request.tenant``'s own queue)."""
+        with tracing.span("eco.submit"):
+            ticket = self._admit(request, priority, deadline_s)
+        # same yield-once contract as the base submit (see its comment)
+        await asyncio.sleep(0)
+        return ticket
+
+    def _admit(self, request: "Request", priority: int,
+               deadline_s: Optional[float]) -> Ticket:
         loop = asyncio.get_running_loop()
         ticket = Ticket(request, priority, deadline_s, loop.create_future())
         if self._closed:
@@ -268,8 +277,6 @@ class AdmissionShard(Orchestrator):
             self.admitted += 1
             self._tstats(tenant)["admitted"] += 1
         self._arrival.set()
-        # same yield-once contract as the base submit (see its comment)
-        await asyncio.sleep(0)
         return ticket
 
     def _purge_tenant_lapsed(self, tenant: str) -> int:
@@ -320,6 +327,7 @@ class AdmissionShard(Orchestrator):
                        n - len(picked))
             for _ in range(take):
                 picked.append(heapq.heappop(q))
+                picked[-1][2].mark("taken")
             self._deficit[tenant] -= take
         for tenant, q in self._tq.items():
             if not q:
@@ -411,17 +419,13 @@ class AdmissionShard(Orchestrator):
                     await asyncio.wait_for(self._arrival.wait(), remaining)
                 except asyncio.TimeoutError:
                     break
-            bucket = self._drr_take(self.max_batch)
-            now = time.perf_counter()
-            live = []
-            for t in bucket:
-                if t.deadline_at is not None and now > t.deadline_at:
-                    self._shed(t, "deadline")
-                else:
-                    live.append(t)
+            bid = tracing.new_bucket()
+            with tracing.span("eco.bucket", bid) as sp:
+                live = self._close_bucket(self._drr_take(self.max_batch), bid)
+                sp.count("rows", len(live))
             if live:
                 try:
-                    await self._dispatch(live)
+                    await self._dispatch(live, bid)
                 except Exception as e:  # noqa: BLE001 — fail the bucket,
                     # keep admitting (base-class rationale)
                     for t in live:
@@ -567,8 +571,10 @@ class TenantRouter:
             "shards": [{k: st[k] for k in
                         ("shard_id", "admitted", "shed", "deadline_shed",
                          "batches", "dispatched", "completed", "failed",
-                         "queue_depth")}
+                         "select_passes", "fallback_rows", "queue_depth")}
                        for st in shard_stats],
+            "select_passes": sum(st["select_passes"] for st in shard_stats),
+            "fallback_rows": sum(st["fallback_rows"] for st in shard_stats),
         }
         # per-shard adaptation telemetry (drift monitors, ring fill, sweep
         # counts) when an AdaptationPlane is attached

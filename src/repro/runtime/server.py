@@ -357,8 +357,8 @@ class EcoLLMServer:
         # fromkeys instead of a literal dict: can't drift from the key set
         # this method consumes below when Orchestrator.stats() grows
         admission = (orch.stats() if orch is not None else dict.fromkeys(
-            ("queue_depth", "shed", "deadline_shed", "admitted", "batches"),
-            0))
+            ("queue_depth", "shed", "deadline_shed", "admitted", "batches",
+             "select_passes", "fallback_rows"), 0))
         state = {
             "replicas": fleet["replicas"],
             "hedges": fleet["hedges"],
@@ -374,6 +374,10 @@ class EcoLLMServer:
             "deadline_shed": admission["deadline_shed"],
             "admitted": admission["admitted"],
             "dispatch_batches": admission["batches"],
+            # selection passes run and rows the host OOD fallback decided,
+            # over the default orchestrator and the router's shards
+            "select_passes": admission["select_passes"],
+            "fallback_rows": admission["fallback_rows"],
             "slo_violation_rate": self.tracker.violation_rate,
             "slo_latency_violation_rate": self.tracker.latency_violation_rate,
             "slo_cost_violation_rate": self.tracker.cost_violation_rate,
@@ -400,6 +404,8 @@ class EcoLLMServer:
             # per-tenant offered/admitted/served/shed counters + per-shard
             # admission stats, folded from the router fronting this server
             state["router"] = self._router.stats()
+            for key in ("select_passes", "fallback_rows"):
+                state[key] += state["router"][key]
         with self._domains_lock:
             state["table_versions"] = {
                 n: sel.table_version

@@ -78,8 +78,8 @@ def test_submit_awaitable_mixed_slos_and_events(served):
         names = [n for n, _ in t.events]
         # first_chunk lands between dispatched and completed (streaming is
         # on by default; every served path streams at least one chunk)
-        assert names == ["admitted", "selected", "dispatched", "first_chunk",
-                         "completed"]
+        assert names == ["admitted", "taken", "selected", "dispatched",
+                         "first_chunk", "completed"]
         stamps = [ts for _, ts in t.events]
         assert stamps == sorted(stamps)
 
@@ -200,7 +200,8 @@ def test_per_request_deadline_sheds_before_dispatch(served):
     t, result, stats = asyncio.run(main())
     assert isinstance(result, Overloaded) and result.reason == "deadline"
     assert t.shed and stats["deadline_shed"] == 1
-    assert [n for n, _ in t.events] == ["admitted", "shed"]
+    # taken into a bucket, then shed at the bucket's close
+    assert [n for n, _ in t.events] == ["admitted", "taken", "shed"]
 
 
 def test_priority_orders_admission_under_backlog(served):
@@ -416,12 +417,17 @@ def test_dispatch_sync_failure_keeps_counter_invariant(served):
 
 def test_system_state_reports_admission_counters(served):
     server, test_idx = served
+    before = server.system_state()
     server.handle(Request(prompt="", qid=test_idx[0], slo=SLO()))
     state = server.system_state()
     for key in ("admission_queue_depth", "shed", "deadline_shed",
-                "admitted", "dispatch_batches"):
+                "admitted", "dispatch_batches", "select_passes",
+                "fallback_rows"):
         assert isinstance(state[key], int)
     assert state["admitted"] >= 1 and state["dispatch_batches"] >= 1
+    # one request is one bucket, selected in one pass
+    assert state["select_passes"] - before["select_passes"] == 1
+    assert state["dispatch_batches"] - before["dispatch_batches"] == 1
     assert state["requests"] == server.tracker.total
 
 

@@ -313,6 +313,12 @@ def test_system_state_reports_router_and_shard_attribution(multi):
     assert rt["tenants"]["acme"]["served"] == len(qids)
     assert rt["tenants"]["acme"]["shard"] == router.shard_index("acme")
     assert state["dispatched_by_shard"][shard_tag] - before == len(qids)
+    # the shards' selection passes fold into the router's and the server's
+    shard = rt["shards"][router.shard_index("acme")]
+    assert shard["select_passes"] == shard["batches"] >= 2
+    assert rt["select_passes"] == sum(r["select_passes"]
+                                      for r in rt["shards"])
+    assert state["select_passes"] >= rt["select_passes"]
 
 
 def test_shard_reconfigure_carries_best_per_tenant(multi):

@@ -215,17 +215,20 @@ def test_await_vs_async_for_equivalence(served):
         chunks = [c async for c in t2]
         r2 = await t2
         again = [c async for c in t2]  # exhausted: terminates immediately
+        # t1 settled with nobody iterating: its iterator yields every chunk
+        # that arrived, buffered
+        late = [c async for c in t1]
         await orch.stop()
-        return r1, r2, chunks, again, t1, t2
+        return r1, r2, chunks, again, late, t1, t2
 
-    r1, r2, chunks, again, t1, t2 = asyncio.run(run())
+    r1, r2, chunks, again, late, t1, t2 = asyncio.run(run())
     assert (r1.path_key, r1.accuracy, r1.latency_s, r1.cost_usd) \
         == (r2.path_key, r2.accuracy, r2.latency_s, r2.cost_usd)
     assert chunks and chunks[-1].final and again == []
     assert [c.index for c in chunks] == list(range(len(chunks)))
     lats = [c.latency_s for c in chunks]
     assert lats == sorted(lats)  # cumulative along the chunk timeline
-    assert len(t2.chunk_times) == len(chunks)
+    assert len(late) == len(chunks) and late[-1].final
     for t in (t1, t2):  # t1 streamed too, even though nobody iterated it
         names = [n for n, _ in t.events]
         assert names.index("dispatched") < names.index("first_chunk") \
