@@ -32,13 +32,21 @@ admission loop took it off the queue for a bucket.  Each bucket is numbered
 
 Streaming contract: a ticket is also an async iterator — ``async for chunk
 in ticket`` yields the response's ``GenChunk``s (split-inference drafts or
-whole-model decode spans) in order, exactly once, as the fleet delivers
-them; ``first_chunk`` lands on the timeline between ``dispatched`` and
-``completed``.  The iterator terminates when the ticket settles
-(completed, shed, or failed), so it is safe on non-streaming outcomes too
-— it just yields nothing.  Chunks are a single-consumer side channel; ``await ticket`` is
-unchanged and bit-for-bit identical to the pre-streaming contract (the
-final Response comes from the same non-streamed accounting).
+whole-model decode spans) in order, exactly once, whether the consumer
+starts before the first chunk, midway, or after settle.  The fleet's
+threads buffer each chunk on the ticket and wake the event loop only for a
+consumer parked waiting for one (at most one wake per park), so a ticket
+nobody iterates costs the loop nothing for its chunks.  ``first_chunk``
+lands on the timeline between ``dispatched`` and ``completed``: stamped
+with the first chunk's arrival in the buffer (no earlier than
+``dispatched``), marked on the loop at the first consumer read or at
+settle, whichever comes first.  The iterator terminates when the ticket
+settles (completed, shed, or failed), so it is safe on non-streaming
+outcomes too — it just yields nothing.  Chunks are a single-consumer side
+channel; ``await ticket`` is unchanged and bit-for-bit identical to the
+pre-streaming contract (the final Response comes from the same
+non-streamed accounting).  ``stats()`` counts ``chunks_streamed`` (chunks
+buffered on tickets) and ``chunk_wakes`` (loop wakes made to deliver them).
 
 The synchronous ``EcoLLMServer.handle`` / ``handle_batch`` survive as thin
 compatibility shims over ``dispatch_sync`` — the same bucket-dispatch
@@ -52,6 +60,7 @@ import heapq
 import itertools
 import threading
 import time
+from collections import deque
 from dataclasses import dataclass
 from typing import TYPE_CHECKING, Optional, Union
 
@@ -77,7 +86,11 @@ class Overloaded:
     max_queue: int
 
 
-_STREAM_END = object()  # chunk-queue terminator (pushed when the ticket settles)
+def _wake(waiter: asyncio.Future) -> None:
+    """Resume a parked chunk consumer (on its loop); the waiter of a
+    consumer cancelled while parked is already done."""
+    if not waiter.done():
+        waiter.set_result(None)
 
 
 class Ticket:
@@ -94,14 +107,18 @@ class Ticket:
 
     ``async for chunk in ticket`` consumes the streamed partial results
     (module docstring): ordered, exactly-once, terminated when the ticket
-    settles.  The first delivered chunk stamps ``first_chunk`` on the
-    timeline.  Single consumer:
-    chunks go to whichever iterator reads them first (a second ``async
-    for`` after exhaustion terminates immediately).
+    settles.  Chunks wait in a buffer on the ticket, filled from the
+    fleet's threads; the loop is woken only for a consumer parked on an
+    empty buffer.  ``first_chunk`` on the timeline is the first chunk's
+    arrival in the buffer, no earlier than ``dispatched``.  Single
+    consumer: chunks go to whichever iterator reads them first (a second
+    ``async for`` after exhaustion terminates immediately).
     """
 
     __slots__ = ("request", "priority", "deadline_s", "deadline_at", "events",
-                 "bucket", "row", "_future", "_chunk_q", "_stream_done")
+                 "bucket", "row", "_future", "_chunks", "_chunk_lock",
+                 "_waiter", "_first_chunk_at", "_n_chunks", "_n_wakes",
+                 "_stream_done")
 
     def __init__(self, request: "Request", priority: int,
                  deadline_s: Optional[float], future: asyncio.Future):
@@ -113,7 +130,14 @@ class Ticket:
         self.bucket: Optional[int] = None
         self.row: Optional[int] = None
         self._future = future
-        self._chunk_q: asyncio.Queue = asyncio.Queue()
+        # streaming side channel: the buffer, the parked consumer's waiter,
+        # the end flag and the counters are guarded by _chunk_lock
+        self._chunks: deque = deque()
+        self._chunk_lock = threading.Lock()
+        self._waiter: Optional[asyncio.Future] = None
+        self._first_chunk_at: Optional[float] = None
+        self._n_chunks = 0  # chunks buffered
+        self._n_wakes = 0   # loop wakes scheduled to deliver them
         self._stream_done = False
 
     def mark(self, name: str) -> None:
@@ -141,33 +165,75 @@ class Ticket:
     async def wait(self) -> Union["Response", Overloaded]:
         return await self._future
 
-    # -- streaming side channel (loop-thread only) --------------------------
+    # -- streaming side channel ---------------------------------------------
 
-    def _on_chunk(self, chunk) -> None:
-        """Deliver one streamed chunk (scheduled onto the event loop by the
-        orchestrator's fleet-side chunk forwarder)."""
-        if self._stream_done:
-            return  # settled already (e.g. raced with an error) — drop
-        if self.event("first_chunk") is None:
-            self.mark("first_chunk")
-        self._chunk_q.put_nowait(chunk)
+    def _put_chunk(self, chunk) -> None:
+        """Buffer one streamed chunk; the fleet calls this from its threads
+        (under the flight lock, so puts arrive in order).  The loop is
+        touched only to wake a parked consumer, once per park."""
+        with self._chunk_lock:
+            if self._stream_done:
+                return  # settled already (e.g. raced with an error) — drop
+            if self._first_chunk_at is None:
+                self._first_chunk_at = time.perf_counter()
+            self._chunks.append(chunk)
+            self._n_chunks += 1
+            waiter, self._waiter = self._waiter, None
+        if waiter is not None:
+            try:
+                waiter.get_loop().call_soon_threadsafe(_wake, waiter)
+            except RuntimeError:
+                return  # loop closed mid-stream: nothing can consume chunks
+            with self._chunk_lock:
+                self._n_wakes += 1
+
+    def _mark_first_chunk(self) -> None:
+        """Put the first chunk's arrival on the timeline (loop thread), once.
+        A chunk can reach the buffer before the loop marks ``dispatched``,
+        so the stamp is held to no earlier than that mark."""
+        at = self._first_chunk_at
+        if at is None or self.event("first_chunk") is not None:
+            return
+        dispatched = self.event("dispatched")
+        if dispatched is not None:
+            at = max(at, dispatched)
+        self.events.append(("first_chunk", at))
 
     def _end_stream(self) -> None:
-        """Terminate the chunk iterator; idempotent, called at settle."""
-        if not self._stream_done:
+        """Terminate the chunk iterator and wake a parked consumer;
+        idempotent, called at settle (loop thread)."""
+        with self._chunk_lock:
+            if self._stream_done:
+                return
             self._stream_done = True
-            self._chunk_q.put_nowait(_STREAM_END)
-            tracing.timeline(self.bucket, self.row, self.events)
+            waiter, self._waiter = self._waiter, None
+        if waiter is not None:
+            _wake(waiter)
+        tracing.timeline(self.bucket, self.row, self.events)
 
     async def _iter_chunks(self):
+        marked = False
         while True:
-            item = await self._chunk_q.get()
-            if item is _STREAM_END:
-                # re-arm the terminator so a later `async for` (or a racing
-                # second consumer) terminates instead of hanging forever
-                self._chunk_q.put_nowait(_STREAM_END)
-                return
-            yield item
+            waiter = None
+            with self._chunk_lock:
+                if self._chunks:
+                    chunk = self._chunks.popleft()
+                elif self._stream_done:
+                    return
+                else:
+                    # park; a racing second consumer shares the waiter, so
+                    # the one wake (or settle) resumes both
+                    waiter = self._waiter
+                    if waiter is None or waiter.done():
+                        waiter = self._waiter = \
+                            asyncio.get_running_loop().create_future()
+            if waiter is not None:
+                await waiter
+                continue
+            if not marked:
+                self._mark_first_chunk()
+                marked = True
+            yield chunk
 
     def __aiter__(self):
         return self._iter_chunks()
@@ -240,6 +306,8 @@ class Orchestrator:
         self.failed = 0     # executions whose await re-raises
         self.select_passes = 0  # selection passes run (one per domain group)
         self.fallback_rows = 0  # rows decided by the host OOD fallback
+        self.chunks_streamed = 0  # chunks buffered on settled tickets
+        self.chunk_wakes = 0      # loop wakes made to deliver them
         # online adaptation observer (runtime/adaptation.py); None keeps the
         # settle/shed hooks at a single attribute load on the hot path
         self._adaptation = None
@@ -604,25 +672,10 @@ class Orchestrator:
         for t, (query, _), dec, fut in zip(tickets, resolved, decisions,
                                            futures):
             if self.stream:
-                # register the chunk forwarder BEFORE the done callback:
-                # call_soon_threadsafe is FIFO per thread, so buffered-chunk
-                # replay (inline sequential mode) schedules ahead of settle
-                # and `first_chunk` always precedes `completed`
-                fut.add_chunk_callback(self._chunk_forwarder(t))
+                # chunks are buffered on the ticket; the fleet replays any
+                # that arrived before this subscription, in order
+                fut.add_chunk_callback(t._put_chunk)
             fut.add_done_callback(self._completer(t, query, dec))
-
-    def _chunk_forwarder(self, ticket: Ticket):
-        """Fleet-side chunk callback: hop each chunk onto the loop thread
-        (all ticket state is loop-confined)."""
-        loop = self._loop
-
-        def fwd(chunk):
-            try:
-                loop.call_soon_threadsafe(ticket._on_chunk, chunk)
-            except RuntimeError:
-                pass  # loop closed mid-stream: nothing can consume chunks
-
-        return fwd
 
     def _completer(self, ticket: Ticket, query, decision):
         """Fleet-side completion callback: build the Response off-loop, then
@@ -641,12 +694,17 @@ class Orchestrator:
                     resp, err = None, e  # ticket
 
             def record():
+                ticket._mark_first_chunk()
                 ticket.mark("completed" if err is None else "failed")
                 with self._stats_lock:
                     if err is None:
                         self.completed += 1
                     else:
                         self.failed += 1
+                    # every chunk has arrived: the fleet refuses emits once
+                    # a flight completes
+                    self.chunks_streamed += ticket._n_chunks
+                    self.chunk_wakes += ticket._n_wakes
                     self._note_settled(ticket, resp, err)
 
             def settle():
@@ -723,6 +781,8 @@ class Orchestrator:
                 "failed": self.failed,
                 "select_passes": self.select_passes,
                 "fallback_rows": self.fallback_rows,
+                "chunks_streamed": self.chunks_streamed,
+                "chunk_wakes": self.chunk_wakes,
                 "queue_depth": self._queue_depth(),
                 "max_batch": self.max_batch,
                 "max_queue": self.max_queue,

@@ -571,10 +571,12 @@ class TenantRouter:
             "shards": [{k: st[k] for k in
                         ("shard_id", "admitted", "shed", "deadline_shed",
                          "batches", "dispatched", "completed", "failed",
-                         "select_passes", "fallback_rows", "queue_depth")}
+                         "select_passes", "fallback_rows", "chunks_streamed",
+                         "chunk_wakes", "queue_depth")}
                        for st in shard_stats],
-            "select_passes": sum(st["select_passes"] for st in shard_stats),
-            "fallback_rows": sum(st["fallback_rows"] for st in shard_stats),
+            **{key: sum(st[key] for st in shard_stats)
+               for key in ("select_passes", "fallback_rows",
+                           "chunks_streamed", "chunk_wakes")},
         }
         # per-shard adaptation telemetry (drift monitors, ring fill, sweep
         # counts) when an AdaptationPlane is attached

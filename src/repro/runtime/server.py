@@ -358,7 +358,8 @@ class EcoLLMServer:
         # this method consumes below when Orchestrator.stats() grows
         admission = (orch.stats() if orch is not None else dict.fromkeys(
             ("queue_depth", "shed", "deadline_shed", "admitted", "batches",
-             "select_passes", "fallback_rows"), 0))
+             "select_passes", "fallback_rows", "chunks_streamed",
+             "chunk_wakes"), 0))
         state = {
             "replicas": fleet["replicas"],
             "hedges": fleet["hedges"],
@@ -374,10 +375,13 @@ class EcoLLMServer:
             "deadline_shed": admission["deadline_shed"],
             "admitted": admission["admitted"],
             "dispatch_batches": admission["batches"],
-            # selection passes run and rows the host OOD fallback decided,
-            # over the default orchestrator and the router's shards
+            # selection passes run, rows the host OOD fallback decided,
+            # chunks buffered on tickets and loop wakes made to deliver
+            # them, over the default orchestrator and the router's shards
             "select_passes": admission["select_passes"],
             "fallback_rows": admission["fallback_rows"],
+            "chunks_streamed": admission["chunks_streamed"],
+            "chunk_wakes": admission["chunk_wakes"],
             "slo_violation_rate": self.tracker.violation_rate,
             "slo_latency_violation_rate": self.tracker.latency_violation_rate,
             "slo_cost_violation_rate": self.tracker.cost_violation_rate,
@@ -404,7 +408,8 @@ class EcoLLMServer:
             # per-tenant offered/admitted/served/shed counters + per-shard
             # admission stats, folded from the router fronting this server
             state["router"] = self._router.stats()
-            for key in ("select_passes", "fallback_rows"):
+            for key in ("select_passes", "fallback_rows", "chunks_streamed",
+                        "chunk_wakes"):
                 state[key] += state["router"][key]
         with self._domains_lock:
             state["table_versions"] = {

@@ -321,6 +321,43 @@ def test_system_state_reports_router_and_shard_attribution(multi):
     assert state["select_passes"] >= rt["select_passes"]
 
 
+def test_chunk_counters_fold_per_shard_into_router_and_system_state(multi):
+    """``chunks_streamed`` and ``chunk_wakes`` land on the tenant's shard
+    and fold, summed, into ``TenantRouter.stats()`` and ``system_state()``
+    (checked by delta: the server folds whichever router fronts it)."""
+    server, tests = multi
+    router = TenantRouter(server, [TenantSpec("acme", domain=DOMAINS[1])],
+                          n_shards=2, max_batch=4, max_wait_ms=1.0,
+                          hedge=False)
+    qids = [int(q) for q in tests[DOMAINS[1]][:4]]
+    before = server.system_state()
+
+    async def main():
+        async with router:
+            ts = [await router.submit(Request(prompt="", qid=q,
+                                              tenant="acme"))
+                  for q in qids]
+            read = [c async for c in ts[-1]]  # one consumer, the rest unread
+            await asyncio.gather(*(t.wait() for t in ts))
+            return ts, read
+
+    ts, read = asyncio.run(main())
+    state = server.system_state()
+    rt = state["router"]
+    home = router.shard_index("acme")
+    streamed = sum(t._n_chunks for t in ts)
+    assert streamed == 5 * len(qids) and len(read) == 5
+    wakes = ts[-1]._n_wakes
+    assert wakes >= 1 and all(t._n_wakes == 0 for t in ts[:-1])
+    for row in rt["shards"]:
+        mine = row["shard_id"] == home
+        assert row["chunks_streamed"] == (streamed if mine else 0)
+        assert row["chunk_wakes"] == (wakes if mine else 0)
+    assert rt["chunks_streamed"] == streamed and rt["chunk_wakes"] == wakes
+    assert state["chunks_streamed"] - before["chunks_streamed"] == streamed
+    assert state["chunk_wakes"] - before["chunk_wakes"] == wakes
+
+
 def test_shard_reconfigure_carries_best_per_tenant(multi):
     """Shrinking max_queue keeps each tenant's best (highest-priority,
     earliest) tickets and sheds ONLY that tenant's overflow."""

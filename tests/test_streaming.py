@@ -10,6 +10,8 @@ bounded admission-queue capacity.
 """
 import asyncio
 import random
+import sys
+import threading
 import time
 from collections import defaultdict
 
@@ -21,7 +23,7 @@ from repro.core.paths import MODEL_CATALOG, SPLIT_IMPL
 from repro.core.splitgen import DraftState, generate_split
 from repro.launch.serve import build_server
 from repro.runtime.fleet import Replica, ReplicaFleet
-from repro.runtime.orchestrator import Orchestrator, Overloaded
+from repro.runtime.orchestrator import Orchestrator, Overloaded, Ticket
 from repro.runtime.server import Request
 
 
@@ -258,6 +260,208 @@ def test_stream_off_preserves_response_and_skips_chunk_machinery(served):
     # the final Response does not depend on whether chunks were delivered
     assert (r_on.path_key, r_on.accuracy, r_on.latency_s, r_on.cost_usd) \
         == (r_off.path_key, r_off.accuracy, r_off.latency_s, r_off.cost_usd)
+
+
+def _assert_first_chunk_between(ticket):
+    names = [n for n, _ in ticket.events]
+    assert names.index("dispatched") < names.index("first_chunk") \
+        < names.index("completed")
+    stamps = [ts for _, ts in ticket.events]
+    assert stamps == sorted(stamps)
+
+
+def test_unread_ticket_buffers_chunks_without_waking_the_loop(served):
+    """A ticket nobody iterates settles with no loop wake for its chunks and
+    still yields all of them, in order, when iterated after settle; a
+    consumer iterating from before dispatch gets each chunk exactly once,
+    in order.  Both tickets carry ``first_chunk`` between ``dispatched``
+    and ``completed``, and ``stats()`` counts what the tickets buffered."""
+    server, test_idx = served
+    qid = int(test_idx[1])
+
+    async def run():
+        orch = server.orchestrator(max_batch=8, max_wait_ms=1.0)
+        await orch.start()
+        before = orch.stats()
+        unread = await orch.submit(Request(prompt="", qid=qid))
+        reader = await orch.submit(Request(prompt="", qid=qid))
+        assert reader.event("dispatched") is None  # iterating from before
+        read = [c async for c in reader]
+        await unread
+        assert unread._n_wakes == 0  # settled without a chunk wake
+        late = [c async for c in unread]
+        after = orch.stats()
+        await orch.stop()
+        return unread, reader, read, late, before, after
+
+    unread, reader, read, late, before, after = asyncio.run(run())
+    for chunks in (read, late):
+        assert [c.index for c in chunks] == list(range(5))
+        assert chunks[-1].final and sum(c.tokens for c in chunks) == 150
+    assert 1 <= reader._n_wakes <= len(read)
+    for t in (unread, reader):
+        _assert_first_chunk_between(t)
+    assert after["chunks_streamed"] - before["chunks_streamed"] == 10
+    assert after["chunk_wakes"] - before["chunk_wakes"] == reader._n_wakes
+
+
+async def _until_parked(ticket):
+    """Yield to the loop until the ticket's consumer is parked on an empty
+    buffer."""
+    for _ in range(10_000):
+        if ticket._waiter is not None:
+            return
+        await asyncio.sleep(0)
+    raise AssertionError("consumer never parked")
+
+
+def test_parked_consumer_costs_at_most_one_wake_per_park():
+    """The producer wakes the loop only for a parked consumer, once per
+    park: a burst of chunks while the consumer is parked costs one wake;
+    settle ends the stream without a chunk wake."""
+    async def run():
+        loop = asyncio.get_running_loop()
+        t = Ticket(None, 0, None, loop.create_future())
+        got = []
+
+        async def consume():
+            async for c in t:
+                got.append(c)
+
+        task = asyncio.create_task(consume())
+        await _until_parked(t)  # park 1: before dispatch, nothing buffered
+        t.mark("dispatched")
+        producer = threading.Thread(target=t._put_chunk, args=(0,))
+        producer.start()
+        producer.join(5.0)
+        assert not producer.is_alive()
+        await _until_parked(t)  # park 2, after taking chunk 0
+        assert got == [0]
+        for i in (1, 2, 3):  # one burst, no loop turn in between
+            t._put_chunk(i)
+        await _until_parked(t)  # park 3, after draining the burst
+        assert got == [0, 1, 2, 3]
+        t._end_stream()
+        await asyncio.wait_for(task, 5.0)
+        again = [c async for c in t]
+        return t, got, again
+
+    t, got, again = asyncio.run(run())
+    assert got == [0, 1, 2, 3] and again == []
+    assert t._n_chunks == 4
+    assert t._n_wakes == 2  # parks 1 and 2 were woken by a chunk; 3 by settle
+    assert [n for n, _ in t.events] == ["dispatched", "first_chunk"]
+
+
+def test_first_chunk_buffered_before_dispatched_is_stamped_at_dispatched():
+    """A chunk can reach the buffer before the loop marks ``dispatched``;
+    the settle-time ``first_chunk`` mark is held to no earlier than it."""
+    async def run():
+        t = Ticket(None, 0, None, asyncio.get_running_loop().create_future())
+        t.mark("selected")
+        t._put_chunk("c0")
+        t.mark("dispatched")
+        t._mark_first_chunk()  # what settle does ahead of `completed`
+        t.mark("completed")
+        t._end_stream()
+        return t, [c async for c in t]
+
+    t, chunks = asyncio.run(run())
+    assert chunks == ["c0"] and t._n_wakes == 0
+    assert [n for n, _ in t.events] == ["selected", "dispatched",
+                                        "first_chunk", "completed"]
+    assert t.event("first_chunk") == t.event("dispatched")
+    _assert_first_chunk_between(t)
+
+
+def test_producer_after_loop_closed_does_not_raise():
+    """A fleet thread still streaming after the session's loop closed (its
+    consumer was parked at the close) buffers its chunks and never raises;
+    the wake that cannot be scheduled is dropped and not counted."""
+    held = {}
+
+    async def run():
+        t = Ticket(None, 0, None, asyncio.get_running_loop().create_future())
+        held["ticket"] = t
+
+        async def consume():
+            async for _ in t:
+                pass
+
+        held["task"] = asyncio.create_task(consume())
+        await _until_parked(t)
+
+    asyncio.run(run())  # closes the loop with the consumer parked
+    t, errors = held["ticket"], []
+
+    def produce():
+        try:
+            for i in range(5):
+                t._put_chunk(i)
+        except Exception as e:  # noqa: BLE001 — the assertion reports it
+            errors.append(e)
+
+    producer = threading.Thread(target=produce)
+    producer.start()
+    producer.join(5.0)
+    assert not producer.is_alive() and errors == []
+    assert list(t._chunks) == [0, 1, 2, 3, 4]
+    assert t._n_chunks == 5 and t._n_wakes == 0
+
+
+def test_chunk_buffer_stress_no_lost_wake():
+    """Many producer threads (more than cores) stream into tickets whose
+    consumers park and wake on the loop, at a shortened switch interval:
+    every consumer receives every chunk exactly once, in order, before
+    settle — a lost wake would leave a consumer parked on a non-empty
+    buffer and time out."""
+    n_tickets, n_chunks, n_threads = 48, 40, 24
+
+    async def run():
+        loop = asyncio.get_running_loop()
+        tickets = [Ticket(None, 0, None, loop.create_future())
+                   for _ in range(n_tickets)]
+        got = [[] for _ in tickets]
+
+        async def consume(t, out):
+            async for c in t:
+                out.append(c)
+                if len(out) == n_chunks:
+                    return
+
+        tasks = [asyncio.create_task(consume(t, out))
+                 for t, out in zip(tickets, got)]
+
+        def produce(mine):
+            for i in range(n_chunks):
+                for t in mine:
+                    t._put_chunk(i)
+                if i % 8 == 0:
+                    time.sleep(0.0005)
+
+        threads = [threading.Thread(target=produce,
+                                    args=(tickets[k::n_threads],))
+                   for k in range(n_threads)]
+        for th in threads:
+            th.start()
+        await asyncio.wait_for(asyncio.gather(*tasks), 20.0)
+        for th in threads:
+            th.join(5.0)
+        assert not any(th.is_alive() for th in threads)
+        for t in tickets:
+            t._end_stream()
+        return tickets, got
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        tickets, got = asyncio.run(run())
+    finally:
+        sys.setswitchinterval(old)
+    for t, out in zip(tickets, got):
+        assert out == list(range(n_chunks))
+        assert t._n_chunks == n_chunks
+        assert t._n_wakes <= n_chunks
 
 
 # -- satellite: stop sentinels must not inflate queue depth ------------------
