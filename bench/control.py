@@ -3,11 +3,12 @@
     python3 bench/control.py --workload <cell> --seed <n> --seeds 12 \
         --control-seeds 3 --seconds 3 --precisions high,default
 
-One process builds the cell's deployment once, then runs short windows of
-the cell's own traffic at the cell's own size: ``--seeds`` of them through
-the program as the configuration states it (HIGHEST selection dots; the
-lower readings), then ``--control-seeds`` through new selectors traced
-with each lower precision (the control, which has to fail).  Each window
+One process builds the cell's deployment (kind ``eco_select``) once, then
+runs short windows of the cell's own traffic at the cell's own size:
+``--seeds`` of them through the program as the configuration states it
+(HIGHEST selection dots; the lower readings), then ``--control-seeds``
+through new selectors traced with each lower precision (the control,
+which has to fail).  Each window
 prints one JSON line with its compared numbers; the last line is the
 summary: per number, the largest program reading and the smallest reading
 of each control.
@@ -22,7 +23,7 @@ from pathlib import Path
 sys.path[:0] = [str(Path(__file__).resolve().parents[1])]
 
 from bench import run as R  # noqa: E402
-from bench.harness import deploy, spec  # noqa: E402
+from bench.harness import spec  # noqa: E402
 from bench.harness.precision import set_select_precision  # noqa: E402
 
 
@@ -43,7 +44,8 @@ def main(argv=None) -> int:
         return 2
     R.enable_compile_cache()
     counter = R.CompileCounter()
-    dep = deploy.build(cell.config, args.seed)
+    kind = spec.kind_of(cell)  # eco_select: its selectors and server
+    dep = kind.build(cell.config, args.seed)
     readings: dict[str, dict[str, list]] = {}
     plan = [("highest", args.seeds)] + [
         (p, args.control_seeds) for p in args.precisions.split(",") if p]
@@ -52,9 +54,9 @@ def main(argv=None) -> int:
             if prec != "highest":
                 set_select_precision(prec)
                 for d in dep.domains:
-                    d.rps = deploy.selector(d, cell.config)
-                dep.server.fleet.close()
-                dep = deploy.serve(dep.domains, cell.config, args.seed)
+                    d.rps = kind.selector(d, cell.config)
+                kind.close(dep)
+                dep = kind.serve(dep.domains, cell.config, args.seed)
             for i in range(n):
                 seed = args.seed + 1 + i
                 out = R.measure(dep, cell, seed, args.seconds, False,
@@ -67,7 +69,7 @@ def main(argv=None) -> int:
                     readings.setdefault(prec, {}).setdefault(k, []).append(
                         c["value"])
     finally:
-        dep.server.fleet.close()
+        kind.close(dep)
     summary = {"program_max": {k: max(v) for k, v in
                                readings.get("highest", {}).items()}}
     for prec, vals in readings.items():

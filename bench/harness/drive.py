@@ -23,14 +23,12 @@ import sys
 import threading
 import time
 import traceback
-from dataclasses import dataclass, field
 
 import numpy as np
 
 from bench.harness import traffic as traffic_mod
 
 SETTLE_GRACE_S = 60.0  # how long past the close a request may still settle
-RESERVOIR = 32         # selection passes kept for the stage comparison
 WINDOW_LEAD_S = 0.05   # the first due time lies this far after the start
 TICK_S = 0.02          # how often the loop marks itself alive
 STALL_S = 0.25         # a mark this late is a stall of the loop
@@ -111,55 +109,6 @@ class Record:
         if self.ticket is not None and self._spans is not None:
             return self._spans.select_of.get(id(self.ticket.request))
         return self.select_span
-
-
-@dataclass
-class PassRecorder:
-    """Wraps the jitted selection pass the window drives and keeps a
-    uniform sample (reservoir, from the run seed) of its calls inside the
-    window: inputs and outputs exactly as the program passed and got
-    them, as device arrays (no copy inside the window)."""
-
-    inner: object
-    rng: np.random.Generator
-    on: bool = False
-    calls: int = 0
-    kept: list = field(default_factory=list)
-    lock: threading.Lock = field(default_factory=threading.Lock)
-
-    def __call__(self, state, embs, slo, *did):
-        out = self.inner(state, embs, slo, *did)
-        if self.on:
-            with self.lock:
-                self.calls += 1
-                item = (embs, slo, did[0] if did else None, out)
-                if len(self.kept) < RESERVOIR:
-                    self.kept.append(item)
-                else:
-                    j = int(self.rng.integers(self.calls))
-                    if j < RESERVOIR:
-                        self.kept[j] = item
-        return out
-
-
-def install_recorder(dep, seed: int) -> PassRecorder:
-    """Put a recorder around the selection pass (built and warm)."""
-    rng = np.random.default_rng([seed, 2])
-
-    def bare(fn):  # a deployment measured again keeps one recorder
-        return fn.inner if isinstance(fn, PassRecorder) else fn
-
-    if dep.multi:
-        sharded = dep.server.sharded_selector()
-        state, jitted, vers = sharded._ensure_kernel()
-        rec = PassRecorder(bare(jitted), rng)
-        sharded._kernel_state = (state, rec, vers)
-    else:
-        rps = dep.domains[0].rps
-        rps._ensure_kernel()
-        rec = PassRecorder(bare(rps._fused_pass), rng)
-        rps._fused_pass = rec
-    return rec
 
 
 class SelectTimes:
@@ -365,8 +314,9 @@ class LoopWatch:
                                      default=0.0)}
 
 
-def make_front(dep, mix: dict):
-    """The deployment's front door, not yet started."""
+def make_front(dep, mix: dict, domains: list[str]):
+    """The deployment's front door, not yet started; tenant ``i`` of a
+    router is served by ``domains[i mod D]``."""
     srv = dep.config["serving"]
     if dep.config["front"] == "orchestrator":
         return dep.server.orchestrator(max_batch=srv["max_batch"],
@@ -375,9 +325,8 @@ def make_front(dep, mix: dict):
     from repro.runtime.router import TenantRouter, TenantSpec
 
     ten = mix["tenants"]
-    names = [d.name for d in dep.domains]
     tenants = [TenantSpec(f"tenant{i}", slo_class=ten["slo_class"],
-                          domain=names[i % len(names)])
+                          domain=domains[i % len(domains)])
                for i in range(ten["n"])]
     return TenantRouter(dep.server, tenants, n_shards=srv["n_shards"],
                         max_batch=srv["max_batch"],
@@ -398,17 +347,6 @@ def admission_totals(front) -> dict:
     return tot
 
 
-def make_request(a: traffic_mod.Arrival, multi: bool):
-    from repro.core.slo import SLO
-    from repro.runtime.server import DEFAULT_TENANT, Request
-
-    return Request(prompt="", qid=a.qid,
-                   slo=SLO(max_latency_s=a.max_latency_s,
-                           max_cost_usd=a.max_cost_usd),
-                   tenant=a.tenant or DEFAULT_TENANT,
-                   domain=a.domain if multi else None)
-
-
 async def settle(records: list[Record], deadline: float) -> None:
     """Wait until every recorded ticket settles or ``deadline`` passes."""
     pending = [r.ticket._future for r in records if r.ticket is not None]
@@ -418,19 +356,26 @@ async def settle(records: list[Record], deadline: float) -> None:
     await asyncio.sleep(0)  # let the last done-callbacks copy their records
 
 
-async def warm(front, dep, mix: dict, pools: dict, seed: int) -> None:
-    """Serve ``warm_requests`` through the front door, all at once: fleet
-    threads, executor caches and every bucket shape get used once."""
-    n = int(dep.config["warm_requests"])
+def warm_arrivals(kind, cfg: dict, mix: dict, pools: dict,
+                  seed: int) -> list:
+    """The ``warm_requests`` arrivals of a run, with the kind's fields."""
+    n = int(cfg["warm_requests"])
     rng = np.random.default_rng([seed, 1])
     arrivals = [traffic_mod.Arrival(0.0, *r)
                 for r in traffic_mod.requests(mix, pools, n, rng)]
-    tickets = [await front.submit(make_request(a, dep.multi))
+    return kind.extend(cfg, mix, arrivals, seed)
+
+
+async def warm(front, kind, dep, mix: dict, pools: dict, seed: int) -> None:
+    """Serve ``warm_requests`` through the front door, all at once: fleet
+    threads, executor caches and every bucket shape get used once."""
+    arrivals = warm_arrivals(kind, dep.config, mix, pools, seed)
+    tickets = [await front.submit(kind.make_request(dep, a))
                for a in arrivals]
     await asyncio.wait([t._future for t in tickets], timeout=SETTLE_GRACE_S)
 
 
-async def open_window(front, arrivals, multi: bool, t0: float,
+async def open_window(front, arrivals, make_request, t0: float,
                       spans: Spans | None) -> list[Record]:
     import jax
 
@@ -440,7 +385,7 @@ async def open_window(front, arrivals, multi: bool, t0: float,
         delay = due - time.perf_counter()
         if delay > 0:
             await asyncio.sleep(delay)
-        req = make_request(a, multi)
+        req = make_request(a)
         sent = time.perf_counter()
         if spans is None:
             ticket = await front.submit(req)
@@ -451,7 +396,7 @@ async def open_window(front, arrivals, multi: bool, t0: float,
     return records
 
 
-async def closed_window(front, arrivals, multi: bool, clients: int,
+async def closed_window(front, arrivals, make_request, clients: int,
                         t_end: float, spans: Spans | None) -> list[Record]:
     import jax
 
@@ -461,7 +406,7 @@ async def closed_window(front, arrivals, multi: bool, clients: int,
     async def client():
         while time.perf_counter() < t_end:
             a = next(nxt)
-            req = make_request(a, multi)
+            req = make_request(a)
             sent = time.perf_counter()
             if spans is None:
                 ticket = await front.submit(req)

@@ -1,18 +1,29 @@
 """Find a cell's pieces by name: ``BENCHMARK.json`` at the checkout root,
-``bench/configs/<config>.json``, ``bench/traffic/<traffic>.json`` and
-``bench/metrics/<metric>.py``.  A later cell, configuration, traffic mix or
-per-layer metric is a new file here and a new entry in ``BENCHMARK.json``;
-nothing in this module names one.
+``bench/configs/<config>.json``, the deployment kind the configuration
+names (``"kind"``) at ``bench/kinds/<kind>.py``,
+``bench/traffic/<traffic>.json`` and ``bench/metrics/<metric>.py``.  A
+later cell, configuration, kind, traffic mix or per-layer metric is a new
+file here and a new entry in ``BENCHMARK.json``; nothing in this module
+names one.
 """
 from __future__ import annotations
 
 import importlib.util
 import json
+import sys
 from dataclasses import dataclass
 from pathlib import Path
 
 BENCH_DIR = Path(__file__).resolve().parents[1]
 ROOT = BENCH_DIR.parent
+KINDS_DIR = BENCH_DIR / "kinds"
+
+# What every kind module defines (``bench/kinds/eco_select.py`` documents
+# each): the shared harness calls these and reads nothing else of a
+# deployment but its ``config`` and its ``server``.
+KIND_INTERFACE = ("check_traffic", "check_names", "build", "warm", "close",
+                  "pools", "extend", "make_request", "install_recorders",
+                  "counters", "compare", "layer_context", "info", "cpu_cut")
 
 
 def load_json(path: Path) -> dict:
@@ -39,6 +50,33 @@ def peaks(device_kind: str) -> dict:
         raise KeyError(f"no peaks for device kind {device_kind!r} in "
                        f"bench/peaks.json (known: {sorted(table['devices'])})")
     return table["devices"][device_kind]
+
+
+def kind(name: str):
+    """The module of deployment kind ``name``, ``<KINDS_DIR>/<name>.py``,
+    loaded once as ``bench.kinds.<name>`` (so that a kind that imports
+    another gets the same module); a missing file is a ``KeyError``."""
+    path = KINDS_DIR / f"{name}.py"
+    if not path.is_file():
+        raise KeyError(f"no deployment kind {name!r}: {path} does not exist")
+    mod_name = f"bench.kinds.{name}"
+    mod = sys.modules.get(mod_name)
+    if mod is not None and Path(mod.__file__).resolve() == path.resolve():
+        return mod
+    spec = importlib.util.spec_from_file_location(mod_name, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[mod_name] = mod
+    try:
+        spec.loader.exec_module(mod)
+    except BaseException:
+        del sys.modules[mod_name]
+        raise
+    return mod
+
+
+def kind_of(cell: "Cell"):
+    """The kind module of ``cell``'s configuration."""
+    return kind(cell.config["kind"])
 
 
 def metric_reader(name: str):

@@ -15,7 +15,9 @@ A mix file holds:
 * ``tenants`` (optional): ``{"n": n, "zipf": a, "slo_class": name}``:
   ``n`` tenants with Zipf(``a``) shares, tenant ``i`` on domain
   ``i mod D``;
-* the request form: ``"qid"`` (the query arrives already embedded).
+* ``request``: the request form, which the configuration's deployment
+  kind reads and refuses where it does not serve it (``"qid"``: the query
+  arrives already embedded).
 
 Arrivals are a Poisson process drawn from the run seed: each phase of
 each cycle holds a Poisson number of arrivals (mean rate x length) at

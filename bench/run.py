@@ -3,11 +3,11 @@
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 One process, from the root of a checkout: refuse any platform but a TPU
-with the chips the cell asks for; build the cell's deployment
-(``bench/harness/deploy.py``) and warm every bucket shape its traffic
-uses (set-up, ``setup_s``); drive the cell's traffic for ``--seconds``
-through the program's front door; compare what the window produced with
-the float64 reference (``bench/harness/check.py``); print one JSON line.
+with the chips the cell asks for; build the cell's deployment by the kind
+its configuration names (``bench/kinds/<kind>.py``) and warm every shape
+its traffic uses (set-up, ``setup_s``); drive the cell's traffic for
+``--seconds`` through the program's front door; compare what the window
+produced with the kind's plain reference; print one JSON line.
 
 With ``--trace 0`` the line carries the cell's end-to-end metrics, with
 ``--trace 1`` its per-layer metrics, read from a profiler trace of the
@@ -23,6 +23,7 @@ T_START = time.perf_counter()
 
 import argparse  # noqa: E402
 import asyncio  # noqa: E402
+import functools  # noqa: E402
 import gc  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
@@ -37,9 +38,7 @@ ROOT = Path(__file__).resolve().parents[1]
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # libtpu writes no logs
 
-import numpy as np  # noqa: E402
-
-from bench.harness import check, drive, e2e, spec  # noqa: E402
+from bench.harness import drive, e2e, spec  # noqa: E402
 from bench.harness import trace as trace_mod  # noqa: E402
 from bench.harness import traffic as traffic_mod  # noqa: E402
 
@@ -126,40 +125,44 @@ def start_trace(log_dir: str) -> None:
     jax.profiler.start_trace(log_dir, profiler_options=opts)
 
 
+def schedule(kind, cfg: dict, mix: dict, pools: dict, seconds: float,
+             seed: int) -> list:
+    """The window's arrivals from the run seed (closed loop: the sequence
+    the clients take from), with the kind's own fields."""
+    if mix["loop"] == "closed":
+        n = int(mix["max_rate_qps"] * seconds) + mix["outstanding"]
+        arrivals = traffic_mod.closed_loop(mix, pools, n, seed)
+    else:
+        arrivals = traffic_mod.open_loop(mix, pools, seconds, seed)
+    return kind.extend(cfg, mix, arrivals, seed)
+
+
 async def session(dep, cell: spec.Cell, seed: int, seconds: float,
                   trace_dir: str | None, counter: CompileCounter) -> dict:
     """Warm the front door, run the window, let it drain."""
     import jax
 
+    kind = spec.kind_of(cell)
     mix = cell.traffic
-    pools = {d.name: d.pool_qids for d in dep.domains}
-    front = drive.make_front(dep, mix)
+    pools = kind.pools(dep)
+    front = drive.make_front(dep, mix, list(pools))
     watch = drive.LoopWatch()
     await front.start()
     try:
-        await drive.warm(front, dep, mix, pools, seed)
-        rec = drive.install_recorder(dep, seed)
+        await drive.warm(front, kind, dep, mix, pools, seed)
+        spans = drive.Spans() if trace_dir is not None else None
+        rec = kind.install_recorders(dep, seed, spans)
         selects = drive.SelectTimes()
         for orch in drive.orchestrators(front):
             selects.wrap(orch)
-        spans = None
-        if trace_dir is not None:
-            spans = drive.Spans()
+        if spans is not None:
             for orch in drive.orchestrators(front):
                 spans.wrap_select(orch)
-            for d in dep.domains:
-                spans.wrap_fleet(d.executor)
-            if dep.multi:
-                spans.wrap_sharded(dep.server.sharded_selector())
         closed = mix["loop"] == "closed"
-        if closed:
-            n = int(mix["max_rate_qps"] * seconds) + mix["outstanding"]
-            arrivals = traffic_mod.closed_loop(mix, pools, n, seed)
-        else:
-            arrivals = traffic_mod.open_loop(mix, pools, seconds, seed)
-        traces0 = deploy_traces(dep)
+        arrivals = schedule(kind, dep.config, mix, pools, seconds, seed)
+        request = functools.partial(kind.make_request, dep)
+        counts0 = kind.counters(dep)
         adm0 = drive.admission_totals(front)
-        hedges0 = dep.server.fleet.hedge_count
         # the deployment's objects live for the whole run: move them out of
         # the collector's reach so that a full collection of set-up garbage
         # does not stall the window at a random point
@@ -181,11 +184,11 @@ async def session(dep, cell: spec.Cell, seed: int, seconds: float,
             window.__enter__()
         if closed:
             records = await drive.closed_window(
-                front, arrivals, dep.multi, mix["outstanding"], t_close,
+                front, arrivals, request, mix["outstanding"], t_close,
                 spans)
         else:
-            records = await drive.open_window(front, arrivals, dep.multi,
-                                              t0, spans)
+            records = await drive.open_window(front, arrivals, request, t0,
+                                              spans)
             if time.perf_counter() < t_close:
                 await asyncio.sleep(t_close - time.perf_counter())
         if window is not None:
@@ -197,8 +200,7 @@ async def session(dep, cell: spec.Cell, seed: int, seconds: float,
         if trace_dir is not None:
             jax.profiler.stop_trace()
         adm1 = drive.admission_totals(front)
-        traces1 = deploy_traces(dep)
-        hedges = dep.server.fleet.hedge_count - hedges0
+        counts1 = kind.counters(dep)
     finally:
         await watch.stop()
         await front.stop()
@@ -206,15 +208,8 @@ async def session(dep, cell: spec.Cell, seed: int, seconds: float,
             "loop": {**watch.summary(t0), **selects.summary(t0)},
             "setup_s": setup_s, "t0": t0, "t_close": t_close,
             "admission": {k: adm1[k] - adm0[k] for k in adm0},
-            "kernel_traces_in_window": traces1 - traces0,
-            "fleet_hedges": hedges,
+            "counters": {k: counts1[k] - counts0[k] for k in counts0},
             "max_batch": dep.config["serving"]["max_batch"]}
-
-
-def deploy_traces(dep) -> int:
-    if dep.multi:
-        return dep.server.sharded_selector().kernel_trace_count
-    return dep.domains[0].rps.kernel_trace_count
 
 
 def peak_memory() -> int | None:
@@ -226,65 +221,13 @@ def peak_memory() -> int | None:
     return max(peaks) if peaks else None
 
 
-def served_rows(records) -> list:
-    """(domain, qid, max_lat, max_cost, path_key, set_id, fallback) of every
-    request the window served."""
-    return [(r.arrival.domain, r.arrival.qid, r.arrival.max_latency_s,
-             r.arrival.max_cost_usd, *r.decision)
-            for r in records if r.outcome == "ok"]
-
-
-def unsettled(records) -> int:
-    """Requests with no response: failed, or still open."""
-    return sum(1 for r in records if r.outcome not in ("ok", "shed"))
-
-
-def compare(dep, res: dict, seed: int) -> dict:
-    """The numbers that decide ``correct``, each with its limit."""
-    from bench.harness.reference import Reference
-
-    limits = dep.config["check_limits"]
-    refs = {d.name: Reference(d.ref) for d in dep.domains}
-    names = [d.name for d in dep.domains]
-    emb = {d.name: d.data.query_embeddings for d in dep.domains}
-    passes = [(np.asarray(e), np.asarray(s), None if did is None else
-               int(did), tuple(np.asarray(x) for x in out))
-              for e, s, did, out in res["recorder"].kept]
-    gap, n_dec, skip_dec = check.decision_gap(
-        refs, served_rows(res["records"]),
-        lambda dom, q: emb[dom][q], seed)
-    err, n_rows, skip_rows = check.score_err(refs, passes, names)
-    res["check_counts"] = {"decisions": n_dec, "decision_ties": skip_dec,
-                           "pass_rows": n_rows, "pass_row_ties": skip_rows,
-                           "passes": len(passes)}
-    return {"decision_gap": (gap, limits["decision_gap"]),
-            "score_err": (err, limits["score_err"]),
-            "unsettled": (unsettled(res["records"]), limits["unsettled"])}
-
-
 def per_layer(dep, cell: spec.Cell, res: dict, trace: dict,
               device: dict) -> tuple[dict, dict]:
-    spans = res["spans"]
-    if dep.multi:
-        first = dep.domains[0].name
-        pass_rows = [(n, first if d == "default" else d)
-                     for n, d in spans.pass_rows]
-    else:
-        pass_rows = [(n, dep.domains[0].name) for _, _, n in spans.select]
-    shapes = {}
-    n_max = max(d.ref["log_emb"].shape[0] for d in dep.domains)
-    for d in dep.domains:
-        n = d.ref["log_emb"].shape[0]
-        # the corpus as the retrieve stage is called: padded to the sharded
-        # maximum, or to the kernel's 512-row tile
-        padded = n_max if dep.multi else -(-n // 512) * 512
-        shapes[d.name] = {"n_log": n, "n_log_padded": padded,
-                          "n_sets": d.ref["protos"].shape[0]}
     ctx = SimpleNamespace(
-        records=res["records"], spans=spans, trace=trace,
+        records=res["records"], spans=res["spans"], trace=trace,
         admission=res["admission"], max_batch=res["max_batch"],
-        peaks=spec.peaks(device["kind"]), config=dep.config, shapes=shapes,
-        pass_rows=pass_rows, notes={})
+        peaks=spec.peaks(device["kind"]), config=dep.config, notes={},
+        **spec.kind_of(cell).layer_context(dep, res))
     out = {}
     for m in cell.per_layer:
         value = spec.metric_reader(m["name"])(ctx)
@@ -303,19 +246,19 @@ def generator_lag(records) -> dict:
 
 def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool) -> dict:
     """One run of ``cell``; returns the result line as a dict."""
+    kind = spec.kind_of(cell)
+    kind.check_traffic(cell.traffic)
     device = accelerator(cell.chips)
     cache = enable_compile_cache()
     counter = CompileCounter()
-    from bench.harness import deploy
-
     try:
         t = time.perf_counter()
-        dep = deploy.build(cell.config, seed)
+        dep = kind.build(cell.config, seed)
         build_s = time.perf_counter() - t
         try:
             out = measure(dep, cell, seed, seconds, trace, device, counter)
         finally:
-            dep.server.fleet.close()
+            kind.close(dep)
     finally:
         counter.close()
     out["info"].update(build_s=build_s, compile_cache=cache)
@@ -327,9 +270,8 @@ def measure(dep, cell: spec.Cell, seed: int, seconds: float, trace: bool,
             device: dict, counter: CompileCounter,
             with_checks: bool = True) -> dict:
     """Warm ``dep`` for the cell, run one window and read it."""
-    from bench.harness import deploy
-
-    deploy.warm_selection(dep)
+    kind = spec.kind_of(cell)
+    kind.warm(dep)
     device = dict(device)
     trace_dir = tempfile.mkdtemp(prefix="bench-trace-") if trace else None
     try:
@@ -344,7 +286,7 @@ def measure(dep, cell: spec.Cell, seed: int, seconds: float, trace: bool,
             shutil.rmtree(trace_dir, ignore_errors=True)
     records = [r for r in res["records"] if r.due <= res["t_close"]]
     res["records"] = records
-    checks = compare(dep, res, seed) if with_checks else {}
+    checks = kind.compare(dep, res, seed) if with_checks else {}
     notes = {}
     if trace:
         metrics, notes = per_layer(dep, cell, res, reduced, device)
@@ -367,17 +309,11 @@ def measure(dep, cell: spec.Cell, seed: int, seconds: float, trace: bool,
         "setup_s": res["setup_s"],
         "setup_compile_events": dict(counter.setup),
         "window_compile_events": dict(counter.in_window),
-        "kernel_traces_in_window": res["kernel_traces_in_window"],
-        "fleet_hedges": res["fleet_hedges"],
-        "selection_passes_in_window": res["recorder"].calls,
+        **res["counters"],
         "buckets_in_window": res["admission"]["batches"],
         "drain_s": last - res["t_close"],
         **generator_lag(records), **res["loop"],
-        **res.get("check_counts", {}), **notes,
-        **{f"sets.{d.name}": int(d.ref["protos"].shape[0])
-           for d in dep.domains},
-        **{f"sets_unmatched.{d.name}": d.ref["sets_unmatched"]
-           for d in dep.domains},
+        **res.get("check_counts", {}), **notes, **kind.info(dep, res),
     }
     if trace:
         out["info"]["trace_ops"] = reduced["op_n"]

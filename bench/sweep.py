@@ -22,7 +22,7 @@ from pathlib import Path
 sys.path[:0] = [str(Path(__file__).resolve().parents[1])]
 
 from bench import run as R  # noqa: E402
-from bench.harness import deploy, spec  # noqa: E402
+from bench.harness import spec  # noqa: E402
 
 
 def poisson(cell: spec.Cell, rate: float) -> spec.Cell:
@@ -49,7 +49,8 @@ def main(argv=None) -> int:
         return 2
     R.enable_compile_cache()
     counter = R.CompileCounter()
-    dep = deploy.build(cell.config, args.seed)
+    kind = spec.kind_of(cell)
+    dep = kind.build(cell.config, args.seed)
     try:
         for i, rate in enumerate(float(x) for x in args.rates.split(",")):
             out = R.measure(dep, poisson(cell, rate), args.seed + i,
@@ -61,7 +62,7 @@ def main(argv=None) -> int:
                 "metrics": out["metrics"], "info": out["info"]})),
                 flush=True)
     finally:
-        dep.server.fleet.close()
+        kind.close(dep)
     return 0
 
 

@@ -1,5 +1,5 @@
-"""A cell cut to a size the CPU test run holds: a few hundred log rows per
-domain and a low rate.  Used by the CPU tests only."""
+"""A cell cut to a size the CPU test run holds: its kind's CPU cut of the
+configuration and a low rate.  Used by the CPU tests only."""
 from __future__ import annotations
 
 import copy
@@ -22,9 +22,7 @@ CPU_PEAKS = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
 def tiny_cell(name: str, rate_scale: float = 1 / 8) -> spec.Cell:
     """``name`` cut to the CPU."""
     c = spec.cell(name)
-    cfg = copy.deepcopy(c.config)
-    for d in cfg["domains"]:
-        d.update(log_rows=600, pool=48)
+    cfg = spec.kind_of(c).cpu_cut(c.config)
     cfg["warm_requests"] = 32
     mix = copy.deepcopy(c.traffic)
     for ph in mix.get("arrivals", {}).get("phases", []):
@@ -47,9 +45,14 @@ def run_tiny(name: str, seed: int, seconds: float = 1.0,
              trace: bool = False, **kw) -> dict:
     """One whole run of a tiny cell with the look for a chip skipped; the
     persistent compilation cache is left off."""
+    return run_cell(tiny_cell(name, **kw), seed, seconds, trace)
+
+
+def run_cell(cell: spec.Cell, seed: int, seconds: float = 1.0,
+             trace: bool = False) -> dict:
+    """``run.run_cell`` on the CPU, as ``run_tiny`` makes it."""
     from bench import run as R
 
-    cell = tiny_cell(name, **kw)
     saved = (R.accelerator, R.enable_compile_cache, R.spec.peaks)
     R.accelerator = cpu_device
     R.enable_compile_cache = lambda: "off"

@@ -65,8 +65,22 @@ def test_config_files_match_entries():
         cfg = spec.config(c["name"])
         assert c["file"] == f"bench/configs/{c['name']}.json"
         assert cfg["source"] == c["source"] and cfg["reduced"] == c["reduced"]
-        assert set(cfg["check_limits"]) == {"decision_gap", "score_err",
-                                            "unsettled"}
+        names = spec.kind(cfg["kind"]).check_names()
+        assert len(set(names)) == len(names)
+        assert set(cfg["check_limits"]) == set(names)
+
+
+@pytest.mark.parametrize("config", [c["name"] for c in BENCH["configs"]])
+def test_kind_has_the_full_interface(config):
+    kind = spec.kind(spec.config(config)["kind"])
+    assert kind is spec.kind(spec.config(config)["kind"])  # loaded once
+    for name in spec.KIND_INTERFACE:
+        assert callable(getattr(kind, name, None)), (config, name)
+
+
+def test_missing_kind_names_its_file():
+    with pytest.raises(KeyError, match=r"kinds/no_such_kind\.py"):
+        spec.kind("no_such_kind")
 
 
 def test_metric_lists_follow_workloads():
